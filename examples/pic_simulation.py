@@ -26,7 +26,7 @@ import json
 
 import numpy as np
 
-from repro import obs
+from repro import backend, obs
 from repro.core import prefix, registry, threed
 from repro.data.pipeline import ParticleFeed
 
@@ -145,6 +145,7 @@ def main():
     ap.add_argument("--trace", default=None, metavar="FILE",
                     help="write a Chrome/Perfetto trace of the 3D run")
     args = ap.parse_args()
+    backend.enable_compile_cache()
     if args.algo is None:
         main_2d()
     else:

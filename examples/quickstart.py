@@ -4,10 +4,12 @@
 """
 import numpy as np
 
+from repro import backend
 from repro.core import prefix, registry
 
 
 def main():
+    backend.enable_compile_cache()
     # a PIC-MAG-like particle density on a 256x256 grid
     A = prefix.pic_like_instance(256, 256, iteration=20_000)
     gamma = prefix.prefix_sum_2d(A)

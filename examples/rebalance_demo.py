@@ -38,8 +38,11 @@ if args.devices > 1:
 
 import time                                                       # noqa: E402
 
+from repro import backend                                         # noqa: E402
 from repro.rebalance import faults, migrate, policy, runtime, \
     stream                                                        # noqa: E402
+
+backend.enable_compile_cache()
 
 T, N, P, M = 32, 64, 4, 16
 

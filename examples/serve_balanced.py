@@ -13,11 +13,13 @@ import jax
 import jax.numpy as jnp
 
 import repro.configs as configs
+from repro import backend
 from repro.models import api
 from repro.serve import batcher
 
 
 def main():
+    backend.enable_compile_cache()
     rng = np.random.default_rng(0)
     cfg = configs.get_smoke("qwen3_0_6b")
     model = api.build(cfg)

@@ -20,7 +20,7 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro import obs
+from repro import backend, obs
 from repro.core import prefix, registry
 from repro.rebalance import runtime, stream
 from repro.rebalance.policy import HysteresisPolicy
@@ -33,6 +33,7 @@ def main() -> None:
     ap.add_argument("--size", type=int, default=48)
     ap.add_argument("--m", type=int, default=16)
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     frames = stream.drifting_hotspot(T=args.steps, n1=args.size,
                                      n2=args.size, seed=0)

@@ -9,6 +9,7 @@ data pipeline are identical to the production driver.)
 """
 import argparse
 
+from repro import backend
 from repro.launch import train
 
 
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     import repro.configs as configs
     import repro.configs.qwen3_0_6b as q

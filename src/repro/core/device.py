@@ -53,6 +53,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.backend import use_pallas_default
+
 # ---------------------------------------------------------------------------
 # probes
 
@@ -415,8 +417,9 @@ def _exact_1d_bounds_int(p: jnp.ndarray, m: int):
 
 def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
                               speeds: jnp.ndarray | None = None, *,
-                              k: int = 15, use_pallas_probe: bool = False,
-                              interpret: bool = True):
+                              k: int = 15,
+                              use_pallas_probe: bool | None = None,
+                              interpret: bool | None = None):
     """Unjitted body of :func:`nicol_optimal_device`.
 
     Returns ``(cuts (m+1,) int32, bottleneck scalar)``.  Integer ``p``
@@ -427,8 +430,9 @@ def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
     normalized by ``search.normalize_speeds`` — uniform vectors should
     be dropped to ``None`` host-side to keep the homogeneous path
     bit-identical).  ``use_pallas_probe`` routes the homogeneous
-    feasibility probe through the ``kernels.probe`` Pallas kernel
-    (``interpret=True`` for CPU) instead of the jnp scan.
+    feasibility probe through the ``kernels.probe`` Pallas kernel instead
+    of the jnp scan; ``None`` resolves it, and ``interpret``, from the
+    platform (:mod:`repro.backend`: the compiled kernel on a TPU).
     """
     n = p.shape[0] - 1
     if speeds is not None:
@@ -461,6 +465,8 @@ def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
         lo = jnp.maximum(total / m, maxel)
         hi = total / m + maxel
 
+    if use_pallas_probe is None:
+        use_pallas_probe = use_pallas_default()
     if use_pallas_probe:
         from repro.kernels.probe import ops as probe_ops
 
@@ -600,8 +606,8 @@ def _collapse_cuts(n2: int, m: int) -> jnp.ndarray:
 
 def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
                            speeds: jnp.ndarray | None = None, k: int = 15,
-                           use_pallas_probe: bool = False,
-                           interpret: bool = True):
+                           use_pallas_probe: bool | None = None,
+                           interpret: bool | None = None):
     """Unjitted body of :func:`jag_pq_opt_device` (JAG-PQ-OPT on device).
 
     gamma: (n1+1, n2+1) device prefix sums, 'hor' orientation (transpose
@@ -619,7 +625,9 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
     solver to its 1e-9 tolerance).  ``use_pallas_probe`` routes the
     per-stripe column feasibility probes through the ``kernels.probe``
     Pallas kernel — with a Pallas SAT stage in front this is the fused
-    SAT -> probe -> cut path, no host round-trip anywhere.
+    SAT -> probe -> cut path, no host round-trip anywhere.  ``None``
+    resolves it, and ``interpret``, from the platform
+    (:mod:`repro.backend`: the compiled kernel on a TPU).
     """
     n1 = gamma.shape[0] - 1
     n2 = gamma.shape[1] - 1
@@ -687,6 +695,8 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
                                           p_s[n2] / Q
                                           + jnp.max(jnp.diff(p_s))))(sm)
 
+    if use_pallas_probe is None:
+        use_pallas_probe = use_pallas_default()
     if use_pallas_probe:
         from repro.kernels.probe import ops as probe_ops
 

@@ -199,14 +199,19 @@ def sgorp_plan_impl(gamma, speed_grid=None, *, grid, max_iters: int = 256,
 def sgorp_plan_3d_impl(frames, speed_grid=None, *, grid,
                        max_iters: int = 256, patience: int = 32,
                        k: int = 8, rounds: int = 8, gamma_dtype=None,
-                       use_pallas: bool = False, interpret: bool = True):
+                       use_pallas: bool | None = None,
+                       interpret: bool | None = None):
     """The batched 3D planning chain: (T, n1, n2, n3) frames -> stacked
     rectilinear cuts.  ingest -> Gamma3 (``kernels/sat`` rank-3 path) ->
     vmapped warm start + SGORP refine — one jit boundary, so the sharded
-    planner traces it like the 2D chain.  Returns (cuts1 (T, p1+1),
-    cuts2 (T, p2+1), cuts3 (T, p3+1), Lmax (T,), iters (T,),
-    projections (T,))."""
+    planner traces it like the 2D chain.  ``use_pallas=None`` takes the
+    compiled Gamma3 kernel on a TPU and the oracle elsewhere
+    (:mod:`repro.backend`).  Returns (cuts1 (T, p1+1), cuts2 (T, p2+1),
+    cuts3 (T, p3+1), Lmax (T,), iters (T,), projections (T,))."""
+    from repro.backend import use_pallas_default
     from repro.kernels.sat import ops as sat_ops
+    if use_pallas is None:
+        use_pallas = use_pallas_default()
     gamma_dtype = jnp.float32 if gamma_dtype is None else gamma_dtype
     g = sat_ops.gamma3_impl(frames.astype(gamma_dtype),
                             use_pallas=use_pallas, interpret=interpret)

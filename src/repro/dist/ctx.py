@@ -148,11 +148,7 @@ def planner_axes(mesh) -> tuple[str, ...]:
 
 
 def abstract_mesh(shape, axes):
-    """Version-portable ``AbstractMesh`` (jax >= 0.5 takes (shape, axes);
-    0.4.x takes a tuple of (name, size) pairs)."""
+    """A device-less ``AbstractMesh`` of the given axis sizes and names."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))

@@ -7,8 +7,7 @@ candidate) grid in one Pallas launch, so the fused SAT -> probe -> cut
 path of ``jag_pq_opt_device`` never leaves the device between the
 integral image and the realized cuts.
 """
-from .ops import probe_counts, probe_counts_impl, pallas_interpret_default
+from .ops import probe_counts, probe_counts_impl
 from .ref import probe_counts_ref
 
-__all__ = ["probe_counts", "probe_counts_impl", "probe_counts_ref",
-           "pallas_interpret_default"]
+__all__ = ["probe_counts", "probe_counts_impl", "probe_counts_ref"]

@@ -3,35 +3,30 @@
 ``probe_counts_impl`` is the unjitted body for pipelines that fuse the
 probe under an enclosing jit (``core.device``'s exact solvers call it
 inside their ``while_loop`` bodies); ``probe_counts`` is the standalone
-jitted entry point.  ``pallas_interpret_default`` centralizes the
-CPU-CI escape hatch: ``JAX_PALLAS_INTERPRET=1`` forces interpret mode
-(and ``=0`` forces compiled) everywhere it is consulted.
+jitted entry point.  ``interpret=None`` resolves through
+:func:`repro.backend.pallas_interpret_default`: compiled on a TPU,
+interpreted elsewhere, ``JAX_PALLAS_INTERPRET`` overriding both.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+
+from repro.backend import pallas_interpret_default
 
 from .probe import probe_counts_pallas
 from .ref import probe_counts_ref
 
 
-def pallas_interpret_default() -> bool:
-    """Resolve interpret mode: env override, else interpret off-TPU."""
-    v = os.environ.get("JAX_PALLAS_INTERPRET")
-    if v is not None:
-        return v != "0"
-    return jax.default_backend() != "tpu"
-
-
 def probe_counts_impl(p: jnp.ndarray, Ls: jnp.ndarray, cap: int, *,
                       use_pallas: bool = True,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     if not use_pallas:
         return probe_counts_ref(p, Ls, cap)
+    if interpret is None:
+        interpret = pallas_interpret_default()
     return probe_counts_pallas(p, Ls, cap, interpret=interpret)
 
 
@@ -39,7 +34,7 @@ def probe_counts_impl(p: jnp.ndarray, Ls: jnp.ndarray, cap: int, *,
                                              "interpret"))
 def probe_counts(p: jnp.ndarray, Ls: jnp.ndarray, cap: int, *,
                  use_pallas: bool = True,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """Greedy interval counts per (stripe, candidate): (S, N+1) x (S, K)
     -> (S, K) int32, ``cap + 1`` marking infeasible rows.  See
     ``ref.probe_counts_ref`` for the exact semantics contract."""

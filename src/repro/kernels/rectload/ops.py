@@ -6,9 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.probe.ops import pallas_interpret_default
+from repro.backend import pallas_interpret_default
 
-from .rectload import jagged_loads_pallas
+from .rectload import jagged_loads_pallas, load_dtype
 from .ref import jagged_loads_ref
 
 
@@ -17,10 +17,10 @@ def jagged_loads(gamma: jnp.ndarray, row_cuts: jnp.ndarray,
                  interpret: bool | None = None) -> jnp.ndarray:
     """Rectangle loads; accepts 2D Gamma or a leading-frame-axis batch.
 
-    ``interpret=None`` resolves via :func:`pallas_interpret_default`
-    (``JAX_PALLAS_INTERPRET`` override, else interpret off-TPU), matching
-    the probe kernel's convention; resolution happens outside the jit so
-    the cache key carries the concrete mode.
+    Loads are int32 for an integer Gamma and f32 otherwise.
+    ``interpret=None`` resolves via
+    :func:`repro.backend.pallas_interpret_default`; resolution happens
+    outside the jit so the cache key carries the concrete mode.
     """
     if interpret is None:
         interpret = pallas_interpret_default()
@@ -30,9 +30,10 @@ def jagged_loads(gamma: jnp.ndarray, row_cuts: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _jagged_loads(gamma: jnp.ndarray, row_cuts: jnp.ndarray,
-                  col_cuts: jnp.ndarray, *, use_pallas: bool = True,
-                  interpret: bool = True) -> jnp.ndarray:
+                  col_cuts: jnp.ndarray, *, use_pallas: bool,
+                  interpret: bool) -> jnp.ndarray:
     if not use_pallas:
-        return jagged_loads_ref(gamma, row_cuts, col_cuts).astype(jnp.float32)
+        return jagged_loads_ref(gamma.astype(load_dtype(gamma)), row_cuts,
+                                col_cuts)
     return jagged_loads_pallas(gamma, row_cuts, col_cuts,
                                interpret=interpret)

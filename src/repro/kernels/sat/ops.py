@@ -7,6 +7,10 @@ the standalone jitted entry points.  Both accept a ``(n1, n2)`` frame or
 a ``(B, n1, n2)`` stack — the batch dimension rides the kernel's leading
 grid axis (or the oracle's trailing-axes cumsum), so batched/sharded
 traces never fall back to a per-frame Python loop.
+
+``interpret=None`` resolves through
+:func:`repro.backend.pallas_interpret_default`: compiled on a TPU,
+interpreted elsewhere.
 """
 from __future__ import annotations
 
@@ -15,37 +19,39 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .ref import (gamma3_from_sat, gamma3_ref, gamma_from_sat, gamma_ref,
-                  sat3_ref, sat_ref)
+from repro.backend import pallas_interpret_default
+
+from .ref import gamma3_from_sat, gamma_from_sat, sat3_ref, sat_ref
 from .sat import sat_pallas
 from .sat3d import sat3_pallas
 
 
 def sat_impl(a: jnp.ndarray, *, use_pallas: bool = True,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool | None = None) -> jnp.ndarray:
     if not use_pallas:
         return sat_ref(a)
+    if interpret is None:
+        interpret = pallas_interpret_default()
     return sat_pallas(a, interpret=interpret)
 
 
 def gamma_impl(a: jnp.ndarray, *, use_pallas: bool = True,
-               interpret: bool = True) -> jnp.ndarray:
-    if not use_pallas:
-        return gamma_ref(a)
-    return gamma_from_sat(sat_pallas(a, interpret=interpret))
+               interpret: bool | None = None) -> jnp.ndarray:
+    return gamma_from_sat(sat_impl(a, use_pallas=use_pallas,
+                                   interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def sat(a: jnp.ndarray, *, use_pallas: bool = True,
-        interpret: bool = True) -> jnp.ndarray:
-    """Inclusive 2D prefix sum. ``interpret=True`` runs the Pallas kernel
-    body on CPU (this container); on real TPU pass ``interpret=False``."""
+        interpret: bool | None = None) -> jnp.ndarray:
+    """Inclusive 2D prefix sum of a ``(n1, n2)`` frame or a
+    ``(B, n1, n2)`` stack."""
     return sat_impl(a, use_pallas=use_pallas, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def gamma(a: jnp.ndarray, *, use_pallas: bool = True,
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: bool | None = None) -> jnp.ndarray:
     """The paper's Gamma array: exclusive prefix, shape (..., n1+1, n2+1)."""
     return gamma_impl(a, use_pallas=use_pallas, interpret=interpret)
 
@@ -54,22 +60,23 @@ def gamma(a: jnp.ndarray, *, use_pallas: bool = True,
 # rank-3 array is ambiguous: (B, n1, n2) 2D stack vs (n1, n2, n3) volume.
 
 def sat3_impl(a: jnp.ndarray, *, use_pallas: bool = True,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     if not use_pallas:
         return sat3_ref(a)
+    if interpret is None:
+        interpret = pallas_interpret_default()
     return sat3_pallas(a, interpret=interpret)
 
 
 def gamma3_impl(a: jnp.ndarray, *, use_pallas: bool = True,
-                interpret: bool = True) -> jnp.ndarray:
-    if not use_pallas:
-        return gamma3_ref(a)
-    return gamma3_from_sat(sat3_pallas(a, interpret=interpret))
+                interpret: bool | None = None) -> jnp.ndarray:
+    return gamma3_from_sat(sat3_impl(a, use_pallas=use_pallas,
+                                     interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def sat3(a: jnp.ndarray, *, use_pallas: bool = True,
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: bool | None = None) -> jnp.ndarray:
     """Inclusive 3D prefix sum of a ``(n1, n2, n3)`` volume or a
     ``(B, n1, n2, n3)`` frame stack."""
     return sat3_impl(a, use_pallas=use_pallas, interpret=interpret)
@@ -77,6 +84,6 @@ def sat3(a: jnp.ndarray, *, use_pallas: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def gamma3(a: jnp.ndarray, *, use_pallas: bool = True,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: bool | None = None) -> jnp.ndarray:
     """Exclusive 3D prefix, shape (..., n1+1, n2+1, n3+1)."""
     return gamma3_impl(a, use_pallas=use_pallas, interpret=interpret)

@@ -5,8 +5,8 @@ any rectangle load is four lookups. The paper builds it on the host (~40 ms
 for 512x512); for on-device rebalancing of large grids we build it on-TPU.
 
 TPU-native design (HBM -> VMEM -> VREG):
-- Two separable passes: row-scan (cumsum along the last axis) then
-  column-scan (cumsum along the row axis). Each pass is a single
+- Two separable passes: row-scan (prefix along the last axis) then
+  column-scan (prefix along the row axis). Each pass is a single
   ``pl.pallas_call`` whose grid walks tiles; the *innermost* grid axis
   advances along the scan direction, and a VMEM scratch carries the running
   tile-edge sums between consecutive grid steps (TPU grids execute
@@ -20,9 +20,14 @@ TPU-native design (HBM -> VMEM -> VREG):
 - Tile shapes are multiples of the (8, 128) f32 VREG tiling; the default
   (256, 512) f32 tile is 512 KiB, comfortably inside the ~16 MiB VMEM even
   with input+output+carry resident.
-- The scan itself is ``jnp.cumsum`` on-tile (VPU); no MXU use — this kernel
-  is memory-bound by construction, moving 2 x B x n1 x n2 x 4 bytes per
-  pass.
+- The on-tile scan is a log-step (Hillis-Steele) shift-and-add:
+  ``log2(extent)`` rounds of ``pltpu.roll`` plus an ``iota >= shift`` mask
+  on the VPU.  Mosaic has no ``cumsum`` lowering, and a triangular-ones
+  matmul would go through the MXU, which is not exact for int32 and runs
+  f32 at default precision through bf16 passes; adds of exact integers
+  stay exact, so int32 results are bit-identical to the jnp oracle.  The
+  kernel is memory-bound by construction, moving 2 x B x n1 x n2 x 4
+  bytes per pass.
 """
 from __future__ import annotations
 
@@ -34,30 +39,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def tile_cumsum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Inclusive prefix sum of a 2D tile along ``axis`` (0 or 1).
+
+    Hillis-Steele: round ``s`` adds the tile shifted by ``s`` along the
+    axis, with the wrapped-around head masked to zero — ``ceil(log2(n))``
+    rolls, selects and adds, all on the VPU.
+    """
+    n = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    s = 1
+    while s < n:
+        x = x + jnp.where(idx >= s, pltpu.roll(x, s, axis), 0)
+        s *= 2
+    return x
+
+
 def _row_scan_kernel(x_ref, o_ref, carry_ref):
-    """cumsum along axis 2 of each (1, bm, bn) tile; carry: (1, bm, 1)."""
+    """Prefix along axis 2 of each (1, bm, bn) tile; carry: (bm, 1)."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():  # new (frame, row-band): reset the running edge sums
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    c = jnp.cumsum(x_ref[...], axis=2) + carry_ref[...]
-    o_ref[...] = c
-    carry_ref[...] = c[:, :, -1:]
+    c = tile_cumsum(x_ref[0], 1) + carry_ref[...]
+    o_ref[0] = c
+    carry_ref[...] = c[:, -1:]
 
 
 def _col_scan_kernel(x_ref, o_ref, carry_ref):
-    """cumsum along axis 1 of each (1, bm, bn) tile; carry: (1, 1, bn)."""
+    """Prefix along axis 1 of each (1, bm, bn) tile; carry: (1, bn)."""
     r = pl.program_id(2)
 
     @pl.when(r == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    c = jnp.cumsum(x_ref[...], axis=1) + carry_ref[...]
-    o_ref[...] = c
-    carry_ref[...] = c[:, -1:, :]
+    c = tile_cumsum(x_ref[0], 0) + carry_ref[...]
+    o_ref[0] = c
+    carry_ref[...] = c[-1:, :]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -83,7 +104,7 @@ def sat_pallas(a: jnp.ndarray, *, bm: int = 256, bn: int = 512,
         in_specs=[pl.BlockSpec((1, bm, bn), lambda b, i, j: (b, i, j))],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, m1, m2), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, bm, 1), x.dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, 1), x.dtype)],
         interpret=interpret,
     )(x)
 
@@ -93,7 +114,7 @@ def sat_pallas(a: jnp.ndarray, *, bm: int = 256, bn: int = 512,
         in_specs=[pl.BlockSpec((1, bm, bn), lambda b, j, i: (b, i, j))],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b, j, i: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, m1, m2), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1, bn), x.dtype)],
+        scratch_shapes=[pltpu.VMEM((1, bn), x.dtype)],
         interpret=interpret,
     )(pass1)
 

@@ -1,6 +1,6 @@
 """Pallas TPU kernel: blocked 3D summed-area table (rank-3 prefix sum).
 
-The 3D extension of :mod:`.sat`: three separable passes — cumsum along the
+The 3D extension of :mod:`.sat`: three separable passes — prefix along the
 innermost axis, then the middle axis, then the slab axis — each a single
 ``pl.pallas_call`` whose innermost grid axis advances along the scan
 direction while a VMEM scratch carries the running tile-edge sums (TPU
@@ -19,8 +19,8 @@ Rank-3 grid design:
   under the frame-sharded planner's ``shard_map`` trace; a rank-3 input is
   the ``B=1`` case.
 - Like the 2D kernel this is memory-bound by construction (three passes of
-  2 x B x n1 x n2 x n3 x 4 bytes); the scan itself is on-tile
-  ``jnp.cumsum`` (VPU), no MXU use.
+  2 x B x n1 x n2 x n3 x 4 bytes); the on-tile scan is the 2D kernel's
+  exact log-step roll-and-add (``sat.tile_cumsum``, VPU), no MXU use.
 """
 from __future__ import annotations
 
@@ -31,9 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .sat import tile_cumsum
+
 
 def _scan3_kernel(x_ref, o_ref, carry_ref):
-    """cumsum along axis 3 of each (1, 1, bm, bn) tile; carry (1, 1, bm, 1).
+    """Prefix along axis 3 of each (1, 1, bm, bn) tile; carry (bm, 1).
 
     Grid: (B, slabs, row-bands, col-bands) — innermost walks the scan
     direction, so the carry holds the running right-edge column.
@@ -44,22 +46,22 @@ def _scan3_kernel(x_ref, o_ref, carry_ref):
     def _init():  # new (frame, slab, row-band): reset the edge sums
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    c = jnp.cumsum(x_ref[...], axis=3) + carry_ref[...]
-    o_ref[...] = c
-    carry_ref[...] = c[:, :, :, -1:]
+    c = tile_cumsum(x_ref[0, 0], 1) + carry_ref[...]
+    o_ref[0, 0] = c
+    carry_ref[...] = c[:, -1:]
 
 
 def _scan2_kernel(x_ref, o_ref, carry_ref):
-    """cumsum along axis 2 of each (1, 1, bm, bn) tile; carry (1, 1, 1, bn)."""
+    """Prefix along axis 2 of each (1, 1, bm, bn) tile; carry (1, bn)."""
     r = pl.program_id(3)
 
     @pl.when(r == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    c = jnp.cumsum(x_ref[...], axis=2) + carry_ref[...]
-    o_ref[...] = c
-    carry_ref[...] = c[:, :, -1:, :]
+    c = tile_cumsum(x_ref[0, 0], 0) + carry_ref[...]
+    o_ref[0, 0] = c
+    carry_ref[...] = c[-1:, :]
 
 
 def _scan1_kernel(x_ref, o_ref, carry_ref):
@@ -96,7 +98,7 @@ def sat3_pallas(a: jnp.ndarray, *, bm: int = 128, bn: int = 256,
     x = jnp.pad(x, ((0, 0), (0, 0), (0, pad2), (0, pad3)))  # zero: safe
     m2, m3 = x.shape[2], x.shape[3]
 
-    # pass 1: cumsum along axis 3 within each (frame, slab)
+    # pass 1: prefix along axis 3 within each (frame, slab)
     pass1 = pl.pallas_call(
         _scan3_kernel,
         grid=(B, n1, m2 // bm, m3 // bn),  # innermost walks along axis 3
@@ -105,11 +107,11 @@ def sat3_pallas(a: jnp.ndarray, *, bm: int = 128, bn: int = 256,
         out_specs=pl.BlockSpec((1, 1, bm, bn),
                                lambda b, s, i, j: (b, s, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, n1, m2, m3), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1, bm, 1), x.dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, 1), x.dtype)],
         interpret=interpret,
     )(x)
 
-    # pass 2: cumsum along axis 2 within each (frame, slab)
+    # pass 2: prefix along axis 2 within each (frame, slab)
     pass2 = pl.pallas_call(
         _scan2_kernel,
         grid=(B, n1, m3 // bn, m2 // bm),  # innermost walks down axis 2
@@ -118,7 +120,7 @@ def sat3_pallas(a: jnp.ndarray, *, bm: int = 128, bn: int = 256,
         out_specs=pl.BlockSpec((1, 1, bm, bn),
                                lambda b, s, j, i: (b, s, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, n1, m2, m3), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1, 1, bn), x.dtype)],
+        scratch_shapes=[pltpu.VMEM((1, bn), x.dtype)],
         interpret=interpret,
     )(pass1)
 

@@ -41,16 +41,17 @@ __all__ = ["Plan", "gamma_batch", "jag_m_heur_batch", "plan_stream",
 @functools.partial(jax.jit, static_argnames=("gamma_dtype", "use_pallas",
                                              "interpret"))
 def gamma_batch(frames: jnp.ndarray, *, gamma_dtype=jnp.float32,
-                use_pallas: bool = False,
-                interpret: bool = True) -> jnp.ndarray:
+                use_pallas: bool | None = None,
+                interpret: bool | None = None) -> jnp.ndarray:
     """Gamma for every frame: (T, n1, n2) loads -> (T, n1+1, n2+1) prefixes.
 
     The jitted standalone form of the planner's ingest + SAT stages.
     Frames are cast to ``gamma_dtype`` *before* the scan so accumulation
     happens in that dtype (f32 saturates above 2**24 total load; pass
     ``jnp.float64`` with x64 enabled for large integer loads).
-    ``use_pallas=False`` takes the pure-jnp SAT oracle; on real TPU flip
-    it to lower the blocked Pallas kernel with a leading batch grid axis.
+    ``use_pallas=None`` resolves from the platform (:mod:`repro.backend`):
+    on a TPU the blocked Pallas kernel with a leading batch grid axis,
+    elsewhere the pure-jnp SAT oracle.
     """
     return planner.sat_stage(
         planner.ingest_stage(frames, gamma_dtype=gamma_dtype),
@@ -76,8 +77,8 @@ def jag_m_heur_batch(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
                                              "interpret", "exact"))
 def plan_stream(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
                 rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool = False, interpret: bool = True,
-                exact: bool = False):
+                use_pallas: bool | None = None,
+                interpret: bool | None = None, exact: bool = False):
     """SAT + partitioner for a whole (T, n1, n2) stream under one jit.
 
     Composes the planner's *unjitted* stage bodies directly, so the fused

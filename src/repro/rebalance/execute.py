@@ -23,8 +23,8 @@ kernel with its leading frame axis: one batched launch over the stack
 cuts prices every rectangle's total and retained load on device, and
 their difference is the weight each rectangle received
 (``receipt.rect_received``, cross-checked against the measured pair
-inflows).  Gammas are f32 on device — exact for integer totals below
-2**24, the same envelope as the batched planner.
+inflows).  Gammas of integer weights are int32 on device, so the prices
+are exact up to a total of 2**31; any other Gamma is f32.
 """
 from __future__ import annotations
 
@@ -97,13 +97,22 @@ def _live_loads(plan: Plan, loads_pq: np.ndarray) -> np.ndarray:
     return loads_pq[live]
 
 
+def _device_gammas(*ws: np.ndarray) -> jnp.ndarray:
+    """(len(ws), n1+1, n2+1) Gammas on device: int32 when the weights are
+    integers whose totals fit it (exact), f32 otherwise."""
+    gs = np.stack([prefix.prefix_sum_2d(w) for w in ws])
+    exact = np.issubdtype(gs.dtype, np.integer) \
+        and int(gs[:, -1, -1].max()) < 2 ** 31
+    return jnp.asarray(gs, dtype=jnp.int32 if exact else jnp.float32)
+
+
 def plan_rect_loads(plan: Plan, weights=None, *,
                     interpret: bool | None = None) -> np.ndarray:
     """(m,) per-rectangle loads of ``plan`` computed on device via the
     rectload kernel (host twin: :meth:`Plan.loads` on the frame's Gamma).
     """
     w, _ = _weight_array(plan, weights)
-    g = jnp.asarray(prefix.prefix_sum_2d(w), dtype=jnp.float32)
+    g = _device_gammas(w)[0]
     out = jagged_loads(g, jnp.asarray(plan.row_cuts, dtype=jnp.int32),
                        jnp.asarray(plan._live_col_cuts(), dtype=jnp.int32),
                        interpret=interpret)
@@ -156,10 +165,7 @@ def execute_migration(old: Plan, new: Plan, weights=None, *,
         # per-rectangle receipt: one batched rectload launch prices the
         # adopted plan on [full weights, retained weights] — their
         # difference is what each rectangle received
-        g_full = prefix.prefix_sum_2d(w)
-        g_kept = prefix.prefix_sum_2d(
-            np.where((o == n).reshape(w.shape), w, 0))
-        stack = jnp.asarray(np.stack([g_full, g_kept]), dtype=jnp.float32)
+        stack = _device_gammas(w, np.where((o == n).reshape(w.shape), w, 0))
         rc = jnp.broadcast_to(
             jnp.asarray(new.row_cuts, dtype=jnp.int32),
             (2,) + new.row_cuts.shape)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import use_pallas_default
 from repro.core import device
 from repro.kernels.sat import ops as sat_ops
 from repro.obs import trace as _trace
@@ -108,31 +109,36 @@ def ingest_stage(frames: jnp.ndarray, *,
     return frames.astype(gamma_dtype)
 
 
-def sat_stage(frames: jnp.ndarray, *, use_pallas: bool = False,
-              interpret: bool = True) -> jnp.ndarray:
+def sat_stage(frames: jnp.ndarray, *, use_pallas: bool | None = None,
+              interpret: bool | None = None) -> jnp.ndarray:
     """SAT build: (T, n1, n2) frames -> (T, n1+1, n2+1) Gammas.
 
     Both backends take the batch natively — the Pallas kernel's leading
     batch grid axis (so the blocked path lowers under the sharded trace
     instead of falling back to the jnp oracle) and the oracle's
-    trailing-axes cumsum.  ``use_pallas=False`` is the right default on
-    CPU; flip it on real TPU.
+    trailing-axes cumsum.  ``use_pallas=None`` resolves from the platform
+    (:mod:`repro.backend`): the compiled kernel on a TPU, the oracle
+    elsewhere.
     """
+    if use_pallas is None:
+        use_pallas = use_pallas_default()
     return sat_ops.gamma_impl(frames, use_pallas=use_pallas,
                               interpret=interpret)
 
 
 def partition_stage(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
                     rounds: int = 8, gamma_dtype=None, exact: bool = False,
-                    use_pallas: bool = False, interpret: bool = True):
+                    use_pallas: bool | None = None,
+                    interpret: bool | None = None):
     """Partition: vmapped partitioner over the (T, n1+1, n2+1) Gamma batch.
 
     ``exact=False`` (default) runs JAG-M-HEUR; ``exact=True`` runs the
     device-native exact JAG-PQ-OPT (``device.jag_pq_opt_device_impl``,
     ``Q = m // P`` intervals per stripe — cuts bit-identical to the host
     ``jagged.jag_pq_opt(orient='hor')``), with ``use_pallas`` routing its
-    column probes through the fused ``kernels.probe`` kernel.  Returns
-    (row_cuts (T, P+1), counts (T, P), col_cuts (T, P, *), Lmax (T,)).
+    column probes through the fused ``kernels.probe`` kernel (``None``:
+    on a TPU).  Returns (row_cuts (T, P+1), counts (T, P),
+    col_cuts (T, P, *), Lmax (T,)).
     """
     if exact:
         if m % P != 0:
@@ -150,8 +156,8 @@ def partition_stage(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
 
 def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
                 rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool = False, interpret: bool = True,
-                exact: bool = False):
+                use_pallas: bool | None = None,
+                interpret: bool | None = None, exact: bool = False):
     """The full unjitted chain: ingest -> SAT -> partition.
 
     Every intermediate (frames, Gammas) stays on the executing device;
@@ -174,7 +180,8 @@ def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
 def plan_frames_3d(frames: jnp.ndarray, *, grid: tuple[int, ...],
                    max_iters: int = 256, patience: int = 32, k: int = 8,
                    rounds: int = 8, gamma_dtype=None,
-                   use_pallas: bool = False, interpret: bool = True):
+                   use_pallas: bool | None = None,
+                   interpret: bool | None = None):
     """The rank-3 chain: ingest -> 3D SAT -> vmapped SGORP plan.
 
     The volumetric twin of :func:`plan_frames` for ``(T, n1, n2, n3)``
@@ -226,15 +233,16 @@ def _sharded_plan_fn(mesh, P, m, k, rounds, gamma_dtype, use_pallas,
                      interpret, exact):
     """jit(shard_map(chain)) for one (mesh, signature) — cached so repeat
     calls reuse the compiled executable."""
-    from jax.experimental.shard_map import shard_map
     spec, _ = _dp_spec(mesh)
     body = functools.partial(plan_frames, P=P, m=m, k=k, rounds=rounds,
                              gamma_dtype=gamma_dtype, use_pallas=use_pallas,
                              interpret=interpret, exact=exact)
-    # the exact path's while_loop has no shard_map replication rule;
-    # every computation is frame-local so skipping the check is sound
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=not exact))
+    # every computation is frame-local — no value ever varies across the
+    # mesh except through the sharded time axis — so the varying-axes
+    # check has nothing to prove; it is off because the solvers' scan and
+    # while_loop carries start from unsharded constants
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -242,23 +250,21 @@ def _sharded_plan3d_fn(mesh, grid, max_iters, patience, k, rounds,
                        gamma_dtype, use_pallas, interpret):
     """jit(shard_map(3D chain)) for one (mesh, signature) — cached like
     :func:`_sharded_plan_fn`."""
-    from jax.experimental.shard_map import shard_map
     spec, _ = _dp_spec(mesh)
     body = functools.partial(plan_frames_3d, grid=grid, max_iters=max_iters,
                              patience=patience, k=k, rounds=rounds,
                              gamma_dtype=gamma_dtype, use_pallas=use_pallas,
                              interpret=interpret)
-    # SGORP's lax.while_loop has no shard_map replication rule; every
-    # computation is frame-local so skipping the check is sound (same
-    # reasoning as the exact 2D path above)
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False))
+    # frame-local like the 2D chain above, so the check is off likewise
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
 
 
 def plan_stream_3d(frames, *, m: int, grid: tuple[int, ...] | None = None,
                    mesh=None, max_iters: int = 256, patience: int = 32,
                    k: int = 8, rounds: int = 8, gamma_dtype=None,
-                   use_pallas: bool = False, interpret: bool = True):
+                   use_pallas: bool | None = None,
+                   interpret: bool | None = None):
     """SGORP planning for a whole (T, n1, n2, n3) volume stream.
 
     The rank-3 twin of :func:`plan_stream`: ``mesh=None`` runs the whole
@@ -310,8 +316,8 @@ def plan_stream_3d(frames, *, m: int, grid: tuple[int, ...] | None = None,
 
 def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
                 rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool = False, interpret: bool = True,
-                exact: bool = False):
+                use_pallas: bool | None = None,
+                interpret: bool | None = None, exact: bool = False):
     """SAT + partitioner for a whole (T, n1, n2) stream.
 
     ``mesh=None`` is the single-device reference (identical to
@@ -369,8 +375,8 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
 def iter_plan_slices(frames, *, P: int, m: int, mesh=None,
                      slice_size: int | None = None, k: int = 8,
                      rounds: int = 8, gamma_dtype=None,
-                     use_pallas: bool = False, interpret: bool = True,
-                     exact: bool = False):
+                     use_pallas: bool | None = None,
+                     interpret: bool | None = None, exact: bool = False):
     """Yield ``(t0, t1, batched_slice)`` over the stream, planned lazily.
 
     All slices are dispatched before the first yield — jax dispatch is
@@ -404,8 +410,8 @@ def iter_plan_slices(frames, *, P: int, m: int, mesh=None,
 
 def plan_iter(frames, *, P: int, m: int, mesh=None,
               slice_size: int | None = None, k: int = 8, rounds: int = 8,
-              gamma_dtype=None, use_pallas: bool = False,
-              interpret: bool = True, exact: bool = False):
+              gamma_dtype=None, use_pallas: bool | None = None,
+              interpret: bool | None = None, exact: bool = False):
     """Per-frame :class:`~repro.rebalance.batch_device.Plan` iterator.
 
     The lazy flattening of :func:`iter_plan_slices` — what the runtime's
@@ -426,8 +432,8 @@ def plan_iter(frames, *, P: int, m: int, mesh=None,
 
 def plan_host(frames, *, P: int, m: int, mesh=None, k: int = 8,
               rounds: int = 8, gamma_dtype=None,
-              use_pallas: bool = False, interpret: bool = True,
-              exact: bool = False):
+              use_pallas: bool | None = None,
+              interpret: bool | None = None, exact: bool = False):
     """Whole-stream planning to host Plans (one dispatch, no slicing)."""
     from repro.rebalance import batch_device
     batched = plan_stream(frames, P=P, m=m, mesh=mesh, k=k, rounds=rounds,
@@ -437,8 +443,9 @@ def plan_host(frames, *, P: int, m: int, mesh=None, k: int = 8,
 
 
 def profile_stages(frames, *, P: int, m: int, k: int = 8, rounds: int = 8,
-                   gamma_dtype=None, use_pallas: bool = False,
-                   interpret: bool = True, exact: bool = False, mesh=None
+                   gamma_dtype=None, use_pallas: bool | None = None,
+                   interpret: bool | None = None, exact: bool = False,
+                   mesh=None
                    ) -> tuple[list, dict[str, float]]:
     """Blocking per-stage timing of the planning chain (opt-in profiler).
 

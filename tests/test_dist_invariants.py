@@ -3,10 +3,7 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal containers: fixed-seed shim (tests/_hyp.py)
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as configs
 from repro.dist import cp_balance, ctx, moe_placement, sharding as shd

@@ -126,6 +126,18 @@ def test_receipt_matches_ledger_exactly():
     execute.verify_receipt(old, new, w, receipt=r)
 
 
+def test_plan_rect_loads_exact_past_f32_integers():
+    """Integer frames whose totals exceed 2**24 are priced on an int32
+    Gamma: the rectload kernel equals the host int64 Plan.loads."""
+    frames = np.asarray(stream.drifting_hotspot(2, 40, 56, base=20000,
+                                                seed=6))
+    assert frames[0].sum() > 2 ** 24
+    plan = _plans(frames)[0]
+    got = execute.plan_rect_loads(plan, weights=frames[0])
+    np.testing.assert_array_equal(
+        got, plan.loads(prefix.prefix_sum_2d(frames[0])))
+
+
 def test_identity_plan_moves_nothing():
     frames = np.asarray(stream.static(2, 32, 32, seed=0))
     plan = _plans(frames)[0]

@@ -86,7 +86,7 @@ def test_probe_counts_pallas_matches_ref_and_host(S, n, K, cap, rng):
 
 
 def test_pallas_interpret_default_env_override(monkeypatch):
-    from repro.kernels.probe import pallas_interpret_default
+    from repro.backend import pallas_interpret_default
 
     monkeypatch.setenv("JAX_PALLAS_INTERPRET", "1")
     assert pallas_interpret_default() is True
@@ -158,3 +158,140 @@ def test_gamma3_matches_ref_and_host(shape, rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got),
                                   prefix_sum_3d(a).astype(np.int32))
+
+
+# ---------------------------------------------------------------------------
+# the TPU-compilable kernel forms, swept in interpret mode: tiles small
+# enough that every carry crosses tile edges, ragged extents, B > 1 stacks
+
+
+@pytest.mark.parametrize("shape", [(3, 7, 9), (2, 17, 300), (4, 33, 129)])
+@pytest.mark.parametrize("dtype", ["float32", "int32"])
+def test_sat_small_tiles_ragged_stack(shape, dtype, rng):
+    """Multi-tile carries in both scan directions, per frame of a stack;
+    int32 results bit-identical to the oracle."""
+    from repro.kernels.sat.sat import sat_pallas
+    a = rng.integers(0, 100, shape).astype(dtype)
+    got = np.asarray(sat_pallas(jnp.asarray(a), bm=8, bn=128,
+                                interpret=True))
+    # integer-valued f32 far below 2**24 is exact too
+    np.testing.assert_array_equal(got, np.asarray(sat_ref(jnp.asarray(a))))
+
+
+@pytest.mark.parametrize("shape", [(2, 3, 9, 130), (3, 2, 17, 257)])
+def test_sat3_small_tiles_ragged_stack(shape, rng):
+    from repro.kernels.sat.ref import sat3_ref
+    from repro.kernels.sat.sat3d import sat3_pallas
+    a = rng.integers(0, 100, shape).astype(np.int32)
+    got = np.asarray(sat3_pallas(jnp.asarray(a), bm=8, bn=128,
+                                 interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(sat3_ref(jnp.asarray(a))))
+
+
+def test_tile_cumsum_exact_past_f32_integers(rng):
+    """The log-step scan adds exact int32 values: sums past 2**24 (where
+    f32 would round) stay bit-identical to the oracle."""
+    from repro.kernels.sat.sat import sat_pallas
+    a = rng.integers(1 << 20, 1 << 21, (2, 20, 260)).astype(np.int32)
+    got = np.asarray(sat_pallas(jnp.asarray(a), bm=8, bn=128,
+                                interpret=True))
+    assert int(got.max()) > 1 << 30
+    np.testing.assert_array_equal(got, np.asarray(sat_ref(jnp.asarray(a))))
+
+
+@pytest.mark.parametrize("B,n1,n2,P,Q", [(1, 13, 9, 3, 2), (3, 70, 300, 5, 4),
+                                         (2, 9, 600, 2, 7)])
+def test_rectload_int32_ragged_rows_exact(B, n1, n2, P, Q, rng):
+    """int32 Gammas (totals past 2**24) price exactly, with row counts
+    that are not multiples of the kernel's 8-row block."""
+    from repro.kernels.rectload.rectload import jagged_loads_pallas
+    a = rng.integers(0, 1 << 16, (B, n1, n2)).astype(np.int32)
+    g = gamma_ref(jnp.asarray(a))
+    rc = np.stack([np.concatenate([[0], np.sort(rng.choice(
+        np.arange(1, n1), P - 1, replace=False)), [n1]])
+        for _ in range(B)]).astype(np.int32)
+    cc = np.stack([np.stack([np.concatenate([[0], np.sort(rng.choice(
+        np.arange(1, n2), Q - 1, replace=False)), [n2]]) for _ in range(P)])
+        for _ in range(B)]).astype(np.int32)
+    got = jagged_loads_pallas(g, jnp.asarray(rc), jnp.asarray(cc),
+                              interpret=True)
+    assert got.dtype == jnp.int32
+    want = np.asarray(jagged_loads_ref(g, jnp.asarray(rc), jnp.asarray(cc)))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(got).sum(axis=(1, 2)),
+                                  a.sum(axis=(1, 2)))
+
+
+@pytest.mark.parametrize("S,n,K,cap", [(2, 4, 9, 3), (4, 129, 8, 32),
+                                       (3, 300, 15, 6)])
+def test_probe_ragged_candidates_matches_ref(S, n, K, cap, rng):
+    """Row lengths off the 128-lane grid and candidate counts off the
+    8-sublane grid (the exact path asks for k=8 per stripe)."""
+    from repro.kernels.probe import probe_counts, probe_counts_ref
+    loads = rng.integers(0, 40, (S, n))
+    p = np.concatenate([np.zeros((S, 1), np.int64), loads.cumsum(1)],
+                       axis=1).astype(np.int32)
+    Ls = rng.integers(1, int(p[:, -1].max()) + 2, (S, K)).astype(np.int32)
+    got = probe_counts(jnp.asarray(p), jnp.asarray(Ls), cap,
+                       use_pallas=True, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(probe_counts_ref(jnp.asarray(p), jnp.asarray(Ls), cap)))
+
+
+def test_probe_vmapped_inside_while_loop(rng):
+    """The exact path's use: the probe kernel as the feasibility test of
+    the lockstep column bisection (a ``while_loop``), vmapped over a
+    frame stack — same minimal bottlenecks as the jnp probe."""
+    import functools
+    import jax
+    from repro.core import device
+    from repro.kernels.probe import probe_counts_impl
+
+    T, S, n, Q = 3, 4, 50, 5
+    loads = rng.integers(0, 30, (T, S, n))
+    p = jnp.asarray(np.concatenate(
+        [np.zeros((T, S, 1), np.int64), loads.cumsum(2)], axis=2), jnp.int32)
+
+    def solve(ps, use_pallas):
+        def feasible(cand):
+            return probe_counts_impl(ps, cand, Q, use_pallas=use_pallas,
+                                     interpret=True) <= Q
+        lo, hi = jax.vmap(lambda q: device._exact_1d_bounds_int(q, Q))(ps)
+        return device._wide_bisect_exact_batch(feasible, lo, hi, k=8)
+
+    got = jax.jit(jax.vmap(functools.partial(solve, use_pallas=True)))(p)
+    want = jax.jit(jax.vmap(functools.partial(solve, use_pallas=False)))(p)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# platform resolution and the compile cache (repro.backend)
+
+
+def test_cpu_default_device_resolves_cpu_paths():
+    import jax
+    from repro import backend
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert backend.platform() == "cpu"
+        assert backend.use_pallas_default() is False
+
+
+def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch,
+                                                            tmp_path):
+    import os
+    import jax
+    from repro import backend
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert backend.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        d = backend.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert d == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == d
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

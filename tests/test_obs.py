@@ -16,6 +16,7 @@ import sys
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro import obs
 from repro.core import prefix, registry
@@ -25,10 +26,6 @@ from repro.rebalance import faults as faults_mod
 from repro.rebalance.policy import AlwaysRebalance, HysteresisPolicy
 from repro.serve import batcher
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal containers: fixed-seed shim (tests/_hyp.py)
-    from _hyp import given, settings, strategies as st
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

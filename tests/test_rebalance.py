@@ -169,7 +169,7 @@ def test_gamma_dtype_f64_exact_on_large_loads(rng):
     """f32 prefix sums saturate above 2**24; gamma_dtype=f64 stays exact."""
     A = rng.integers(1 << 20, 1 << 22, (24, 24)).astype(np.int64)
     g = prefix.prefix_sum_2d(A)  # int64, total ~1.7e9 >> 2**24
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rc, ct, cc, L = device.jag_m_heur_device(
             jnp.asarray(g, jnp.float64), P=3, m=8, gamma_dtype=jnp.float64)
         p = batch_device.Plan(np.asarray(rc), np.asarray(ct),

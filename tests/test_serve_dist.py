@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal containers: fixed-seed shim (tests/_hyp.py)
-    from _hyp import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import oned
 from repro.dist import cp_balance, moe_placement
