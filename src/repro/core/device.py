@@ -174,30 +174,37 @@ def jag_m_heur_device_impl(gamma: jnp.ndarray, *, P: int, m: int, k: int = 8,
     Returns (row_cuts (P+1,), counts (P,), col_cuts (P, m_max+1), Lmax)
     with m_max = m - P + 1 (a stripe can never get more than that, since
     every other stripe keeps at least one processor).
+
+    The three stages run under ``jax.named_scope``s, which name the
+    ``op_name`` of their instructions in the compiled HLO: ``heur.rows``
+    (the row 1D solve), ``heur.counts`` (the proportional allocation)
+    and ``heur.stripes`` (the per-stripe bisections and their cuts).
     """
     if gamma_dtype is None:
         gamma_dtype = gamma.dtype if jnp.issubdtype(
             gamma.dtype, jnp.floating) else jnp.float32
     gamma_dtype = jnp.dtype(gamma_dtype)
     n2 = gamma.shape[1] - 1
-    row_prefix = gamma[:, n2].astype(gamma_dtype)
-    row_cuts, _ = optimal_1d_device(row_prefix, P, k=k, rounds=rounds)
+    with jax.named_scope("heur.rows"):
+        row_prefix = gamma[:, n2].astype(gamma_dtype)
+        row_cuts, _ = optimal_1d_device(row_prefix, P, k=k, rounds=rounds)
 
-    stripe_prefix = (jnp.take(gamma, row_cuts[1:], axis=0)
-                     - jnp.take(gamma, row_cuts[:-1], axis=0)
-                     ).astype(gamma_dtype)  # (P, n2+1)
-    loads = stripe_prefix[:, n2]
-    total = jnp.maximum(row_prefix[-1], 1)
+    with jax.named_scope("heur.counts"):
+        stripe_prefix = (jnp.take(gamma, row_cuts[1:], axis=0)
+                         - jnp.take(gamma, row_cuts[:-1], axis=0)
+                         ).astype(gamma_dtype)  # (P, n2+1)
+        loads = stripe_prefix[:, n2]
+        total = jnp.maximum(row_prefix[-1], 1)
 
-    # paper's proportional allocation: ceil((m - P) * load / total), >= 1
-    counts = jnp.ceil((m - P) * loads / total).astype(jnp.int32)
-    counts = jnp.maximum(counts, 1)
+        # paper's proportional allocation: ceil((m - P) * load / total), >= 1
+        counts = jnp.ceil((m - P) * loads / total).astype(jnp.int32)
+        counts = jnp.maximum(counts, 1)
 
-    def give_leftover(counts, _):
-        s = jnp.argmax(loads / counts)
-        return counts.at[s].add(jnp.where(counts.sum() < m, 1, 0)), None
+        def give_leftover(counts, _):
+            s = jnp.argmax(loads / counts)
+            return counts.at[s].add(jnp.where(counts.sum() < m, 1, 0)), None
 
-    counts, _ = jax.lax.scan(give_leftover, counts, None, length=P)
+        counts, _ = jax.lax.scan(give_leftover, counts, None, length=P)
 
     m_max = m - P + 1
 
@@ -220,8 +227,9 @@ def jag_m_heur_device_impl(gamma: jnp.ndarray, *, P: int, m: int, k: int = 8,
         cuts = _probe_cuts_masked(p, m_max, count, hi_f)
         return cuts, _stripe_bottleneck(p, cuts)
 
-    col_cuts, bots = jax.vmap(stripe_optimal)(stripe_prefix, counts)
-    return row_cuts, counts, col_cuts, jnp.max(bots)
+    with jax.named_scope("heur.stripes"):
+        col_cuts, bots = jax.vmap(stripe_optimal)(stripe_prefix, counts)
+        return row_cuts, counts, col_cuts, jnp.max(bots)
 
 
 jag_m_heur_device = jax.jit(
@@ -628,6 +636,13 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
     SAT -> probe -> cut path, no host round-trip anywhere.  ``None``
     resolves it, and ``interpret``, from the platform
     (:mod:`repro.backend`: the compiled kernel on a TPU).
+
+    Without ``speeds`` the four stages run under ``jax.named_scope``s,
+    which name the ``op_name`` of their instructions in the compiled HLO:
+    ``exact.row_bisect`` (the bisection over ``_row_scan``),
+    ``exact.row_realize`` (the row cuts and stripe prefixes),
+    ``exact.col_bisect`` (the per-stripe bisections and their probes)
+    and ``exact.col_realize`` (the column cuts and their loads).
     """
     n1 = gamma.shape[0] - 1
     n2 = gamma.shape[1] - 1
@@ -667,60 +682,64 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
         counts = jnp.full((P,), Q, jnp.int32)
         return row_cuts, counts, col_cuts, jnp.max(bots)
 
-    if integral:
-        lo = (total + m - 1) // m
-        hi = total // m + maxrow // Q + maxcol + 2
-        hi = jnp.maximum(hi, lo)
-    else:
-        lo = total / m
-        hi = (total / m + maxrow / Q + maxcol) * (1 + 1e-9) + 1e-12
-        hi = jnp.maximum(hi, lo)
+    with jax.named_scope("exact.row_bisect"):
+        if integral:
+            lo = (total + m - 1) // m
+            hi = total // m + maxrow // Q + maxcol + 2
+            hi = jnp.maximum(hi, lo)
+        else:
+            lo = total / m
+            hi = (total / m + maxrow / Q + maxcol) * (1 + 1e-9) + 1e-12
+            hi = jnp.maximum(hi, lo)
 
-    def feasible(cand):
-        return jax.vmap(lambda L: _row_scan(gamma, L, P, Q))(cand)
+        def feasible(cand):
+            return jax.vmap(lambda L: _row_scan(gamma, L, P, Q))(cand)
 
-    if integral:
-        L = wide_bisect_exact_device(feasible, lo, hi, k=k)
-    else:
-        L = wide_bisect_float_device(feasible, lo, hi, k=k)
-    row_cuts = _row_scan(gamma, L, P, Q, realize=True)
-    sm = (jnp.take(gamma, row_cuts[1:], axis=0)
-          - jnp.take(gamma, row_cuts[:-1], axis=0))  # (P, n2+1)
+        if integral:
+            L = wide_bisect_exact_device(feasible, lo, hi, k=k)
+        else:
+            L = wide_bisect_float_device(feasible, lo, hi, k=k)
+    with jax.named_scope("exact.row_realize"):
+        row_cuts = _row_scan(gamma, L, P, Q, realize=True)
+        sm = (jnp.take(gamma, row_cuts[1:], axis=0)
+              - jnp.take(gamma, row_cuts[:-1], axis=0))  # (P, n2+1)
 
     # per-stripe exact column solves, lockstep across stripes: one probe
     # round (optionally one Pallas kernel call) serves every open stripe.
-    los, his = jax.vmap(lambda p_s: _exact_1d_bounds_int(p_s, Q)
-                        if integral else (jnp.maximum(p_s[n2] / Q,
-                                                      jnp.max(jnp.diff(p_s))),
-                                          p_s[n2] / Q
-                                          + jnp.max(jnp.diff(p_s))))(sm)
+    with jax.named_scope("exact.col_bisect"):
+        los, his = jax.vmap(
+            lambda p_s: _exact_1d_bounds_int(p_s, Q) if integral
+            else (jnp.maximum(p_s[n2] / Q, jnp.max(jnp.diff(p_s))),
+                  p_s[n2] / Q + jnp.max(jnp.diff(p_s))))(sm)
 
-    if use_pallas_probe is None:
-        use_pallas_probe = use_pallas_default()
-    if use_pallas_probe:
-        from repro.kernels.probe import ops as probe_ops
+        if use_pallas_probe is None:
+            use_pallas_probe = use_pallas_default()
+        if use_pallas_probe:
+            from repro.kernels.probe import ops as probe_ops
 
-        def sfeasible(cand):
-            cnt = probe_ops.probe_counts_impl(
-                sm, cand.astype(sm.dtype), Q,
-                use_pallas=True, interpret=interpret)
-            return cnt <= Q
-    else:
-        def sfeasible(cand):
-            return jax.vmap(lambda p_s, c_s: probe_device(p_s, Q, c_s))(
-                sm, cand)
+            def sfeasible(cand):
+                cnt = probe_ops.probe_counts_impl(
+                    sm, cand.astype(sm.dtype), Q,
+                    use_pallas=True, interpret=interpret)
+                return cnt <= Q
+        else:
+            def sfeasible(cand):
+                return jax.vmap(lambda p_s, c_s: probe_device(p_s, Q, c_s))(
+                    sm, cand)
 
-    if integral:
-        Ls = _wide_bisect_exact_batch(sfeasible, los, his, k=k)
-    else:
-        # float columns: vmapped scalar float bisections (rarely hot)
-        Ls = jax.vmap(lambda p_s, l_s, h_s: wide_bisect_float_device(
-            lambda c: probe_device(p_s, Q, c), l_s, h_s, k=k))(sm, los, his)
-    col_cuts = jax.vmap(lambda p_s, L_s: _greedy_cuts_exact(p_s, Q, L_s))(
-        sm, Ls)
-    bots = jax.vmap(_cut_loads)(sm, col_cuts)
-    counts = jnp.full((P,), Q, jnp.int32)
-    return row_cuts, counts, col_cuts, jnp.max(bots)
+        if integral:
+            Ls = _wide_bisect_exact_batch(sfeasible, los, his, k=k)
+        else:
+            # float columns: vmapped scalar float bisections (rarely hot)
+            Ls = jax.vmap(lambda p_s, l_s, h_s: wide_bisect_float_device(
+                lambda c: probe_device(p_s, Q, c), l_s, h_s, k=k))(
+                    sm, los, his)
+    with jax.named_scope("exact.col_realize"):
+        col_cuts = jax.vmap(
+            lambda p_s, L_s: _greedy_cuts_exact(p_s, Q, L_s))(sm, Ls)
+        bots = jax.vmap(_cut_loads)(sm, col_cuts)
+        counts = jnp.full((P,), Q, jnp.int32)
+        return row_cuts, counts, col_cuts, jnp.max(bots)
 
 
 jag_pq_opt_device = jax.jit(

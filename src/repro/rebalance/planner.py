@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import functools
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +50,7 @@ from repro.rebalance.policy import replan_mode
 __all__ = ["ingest_stage", "sat_stage", "partition_stage", "plan_frames",
            "plan_frames_3d", "plan_stream", "plan_stream_3d",
            "iter_plan_slices", "plan_iter", "plan_host",
-           "profile_stages", "resolve_mesh", "replan_mode"]
+           "resolve_mesh", "replan_mode"]
 
 # How many slices the lazy iterator aims for when none is requested: deep
 # enough that the policy loop starts after ~1/4 of the stream is planned,
@@ -66,20 +65,24 @@ def _check_finite(frames, t0: int, t1: int, *, what: str) -> None:
     through the SAT scan and the device bisection silently produces
     garbage cuts for every frame sharing the slice — so ingest is the
     one place the corruption is still attributable.  Names the offending
-    absolute time-steps and the slice they were batched into.
+    absolute time-steps and the slice they were batched into.  The
+    ``planner.check`` span covers the whole check: the host copy, the
+    dtype test and the NaN scan.
     """
-    arr = np.asarray(frames)
-    if not np.issubdtype(arr.dtype, np.floating):
-        return  # integer loads cannot encode NaN/inf
-    bad = ~np.isfinite(arr.reshape(arr.shape[0], -1)).all(axis=1)
-    if bad.any():
-        steps = (t0 + np.flatnonzero(bad)).tolist()
-        shown = ", ".join(map(str, steps[:8]))
-        more = f" (+{len(steps) - 8} more)" if len(steps) > 8 else ""
-        raise ValueError(
-            f"{what}: non-finite load frame(s) at step(s) {shown}{more} "
-            f"in [{t0}, {t1}) — NaN/inf would silently corrupt every cut "
-            f"in this slice; clean or drop the frames before planning")
+    with _trace.span("planner.check", frames=t1 - t0):
+        arr = np.asarray(frames)
+        if not np.issubdtype(arr.dtype, np.floating):
+            return  # integer loads cannot encode NaN/inf
+        bad = ~np.isfinite(arr.reshape(arr.shape[0], -1)).all(axis=1)
+        if bad.any():
+            steps = (t0 + np.flatnonzero(bad)).tolist()
+            shown = ", ".join(map(str, steps[:8]))
+            more = f" (+{len(steps) - 8} more)" if len(steps) > 8 else ""
+            raise ValueError(
+                f"{what}: non-finite load frame(s) at step(s) {shown}"
+                f"{more} in [{t0}, {t1}) — NaN/inf would silently corrupt "
+                f"every cut in this slice; clean or drop the frames before "
+                f"planning")
 
 
 # ---------------------------------------------------------------------------
@@ -168,13 +171,20 @@ def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
     accumulator to int32 (see :func:`resolve_gamma_dtype`) — with
     ``use_pallas`` this is the fused SAT -> probe -> cut path, no host
     round-trip between integral image and cuts.
+
+    Each stage runs under a ``jax.named_scope`` (``planner.ingest``,
+    ``planner.sat``, ``planner.partition``), which names the ``op_name``
+    of its instructions in the compiled HLO and changes nothing else.
     """
     gamma_dtype = resolve_gamma_dtype(gamma_dtype, exact=exact)
-    g = sat_stage(ingest_stage(frames, gamma_dtype=gamma_dtype),
-                  use_pallas=use_pallas, interpret=interpret)
-    return partition_stage(g, P=P, m=m, k=k, rounds=rounds,
-                           gamma_dtype=gamma_dtype, exact=exact,
-                           use_pallas=use_pallas, interpret=interpret)
+    with jax.named_scope("planner.ingest"):
+        f = ingest_stage(frames, gamma_dtype=gamma_dtype)
+    with jax.named_scope("planner.sat"):
+        g = sat_stage(f, use_pallas=use_pallas, interpret=interpret)
+    with jax.named_scope("planner.partition"):
+        return partition_stage(g, P=P, m=m, k=k, rounds=rounds,
+                               gamma_dtype=gamma_dtype, exact=exact,
+                               use_pallas=use_pallas, interpret=interpret)
 
 
 def plan_frames_3d(frames: jnp.ndarray, *, grid: tuple[int, ...],
@@ -440,55 +450,3 @@ def plan_host(frames, *, P: int, m: int, mesh=None, k: int = 8,
                           gamma_dtype=gamma_dtype, use_pallas=use_pallas,
                           interpret=interpret, exact=exact)
     return batch_device.unstack_plans(batched, tuple(frames.shape[1:]))
-
-
-def profile_stages(frames, *, P: int, m: int, k: int = 8, rounds: int = 8,
-                   gamma_dtype=None, use_pallas: bool | None = None,
-                   interpret: bool | None = None, exact: bool = False,
-                   mesh=None
-                   ) -> tuple[list, dict[str, float]]:
-    """Blocking per-stage timing of the planning chain (opt-in profiler).
-
-    The production paths keep ingest -> SAT -> partition under one jit
-    boundary with async dispatch; this helper deliberately *breaks* that
-    fusion — jitting each stage separately and ``block_until_ready``-ing
-    its output — to attribute wall time to the named stages.  Returns
-    ``(plans, timings)``: the same per-frame Plans as :func:`plan_host`
-    (cuts are bit-identical — stage boundaries don't change any math)
-    and a ``{"ingest", "sat", "partition", "collect"} -> seconds`` dict.
-    Numbers are for attribution only; the fused path beats their sum.
-    On a mesh the sharded chain cannot be split, so the whole sharded
-    ``plan_stream`` is charged to ``partition``.
-    """
-    from repro.rebalance import batch_device
-    frames = jnp.asarray(frames)
-    _check_finite(frames, 0, frames.shape[0], what="profile_stages")
-    shape = tuple(frames.shape[1:])
-    timings: dict[str, float] = {}
-
-    def timed(name, fn, *a):
-        t0 = time.perf_counter()
-        with _trace.span(f"planner.stage.{name}"):
-            out = jax.block_until_ready(fn(*a))
-        timings[name] = time.perf_counter() - t0
-        return out
-
-    if mesh is not None:
-        out = timed("partition", functools.partial(
-            plan_stream, P=P, m=m, mesh=mesh, k=k, rounds=rounds,
-            gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-            interpret=interpret, exact=exact), frames)
-    else:
-        gd = resolve_gamma_dtype(gamma_dtype, exact=exact)
-        ing = timed("ingest", jax.jit(functools.partial(
-            ingest_stage, gamma_dtype=gd)), frames)
-        g = timed("sat", jax.jit(functools.partial(
-            sat_stage, use_pallas=use_pallas, interpret=interpret)), ing)
-        out = timed("partition", jax.jit(functools.partial(
-            partition_stage, P=P, m=m, k=k, rounds=rounds, gamma_dtype=gd,
-            exact=exact, use_pallas=use_pallas, interpret=interpret)), g)
-    t0 = time.perf_counter()
-    with _trace.span("planner.stage.collect"):
-        plans = batch_device.unstack_plans(out, shape)
-    timings["collect"] = time.perf_counter() - t0
-    return plans, timings

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -259,17 +260,92 @@ def test_per_processor_churn_flow_kwarg():
 # planner + policy + serve instrumentation
 
 
-def test_planner_profile_stages_matches_plan_host():
+@pytest.mark.parametrize("traced", [True, False], ids=["on", "off"])
+@pytest.mark.parametrize("entry", ["plan_iter", "plan_stream"])
+def test_planner_check_span(entry, traced):
+    """The host finiteness check runs under ``planner.check``: twice per
+    ``plan_iter`` slice (the slice loop's check and the one inside
+    ``plan_stream``), once per ``plan_stream`` call; nothing is recorded
+    when tracing is off."""
     frames = stream.drifting_hotspot(T=4, n1=24, n2=24, seed=0)
-    ref = planner.plan_host(frames, P=4, m=8)
-    plans, timings = planner.profile_stages(frames, P=4, m=8)
-    assert set(timings) == {"ingest", "sat", "partition", "collect"}
-    assert all(v >= 0 for v in timings.values())
-    assert len(plans) == len(ref)
-    for a, b in zip(ref, plans):
-        np.testing.assert_array_equal(a.row_cuts, b.row_cuts)
-        np.testing.assert_array_equal(np.asarray(a.col_cuts),
-                                      np.asarray(b.col_cuts))
+
+    def run():
+        if entry == "plan_iter":
+            return list(planner.plan_iter(frames, P=4, m=8, slice_size=2))
+        return planner.plan_stream(frames, P=4, m=8)
+
+    if not traced:
+        before = obs.TRACER.events()
+        assert obs.span("planner.check") is obs.trace._NOOP
+        run()
+        assert obs.TRACER.events() == before
+        return
+    with obs.tracing() as tr:
+        run()
+        checks = [e for e in tr.events() if e["name"] == "planner.check"]
+    if entry == "plan_iter":
+        assert [e["args"]["frames"] for e in checks] == [2, 2, 2, 2]
+    else:
+        assert [e["args"]["frames"] for e in checks] == [4]
+    assert all(e["dur"] >= 0 for e in checks)
+
+
+_SCOPES = {
+    False: {"planner.ingest", "planner.sat", "planner.partition",
+            "heur.rows", "heur.counts", "heur.stripes"},
+    # int32 frames already hold the exact path's int32 accumulator, so
+    # its ingest is the identity and emits no op
+    True: {"planner.sat", "planner.partition", "exact.row_bisect",
+           "exact.row_realize", "exact.col_bisect", "exact.col_realize"},
+}
+_FRAME_TABLE = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?(?:\n\n|\Z)",
+    re.S | re.M)
+
+
+def _plan_frames_hlo(exact: bool) -> str:
+    """Compiled HLO of a fresh ``jit(plan_frames)`` on a small int32
+    stream (a new partial, so nothing is reused from an earlier trace)."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    x = jnp.asarray(np.random.default_rng(0).integers(0, 50, (2, 24, 20)),
+                    jnp.int32)
+    fn = jax.jit(functools.partial(planner.plan_frames, P=4, m=8,
+                                   exact=exact))
+    return fn.lower(x).compile().as_text()
+
+
+def _without_metadata(hlo: str) -> str:
+    hlo = _FRAME_TABLE.sub("", hlo)
+    return re.sub(r",?\s*metadata=\{[^{}]*\}", "", hlo)
+
+
+@pytest.mark.parametrize("exact", [False, True])
+def test_plan_frames_hlo_carries_the_stage_scopes(exact):
+    names = set(re.findall(r'op_name="([^"]*)"', _plan_frames_hlo(exact)))
+    found = {part for name in names for part in re.split(r"[/()]", name)}
+    assert _SCOPES[exact] <= found, _SCOPES[exact] - found
+    # under vmap a scope is wrapped, not renamed
+    solver = "exact.row_bisect" if exact else "heur.stripes"
+    assert any(f"vmap({solver})/" in n for n in names)
+
+
+@pytest.mark.parametrize("exact", [False, True])
+def test_plan_frames_scopes_and_spans_change_only_metadata(exact,
+                                                           monkeypatch):
+    """The scopes and the tracer leave the compiled program as it is: the
+    HLO with its metadata stripped is the same with tracing on and off,
+    and the same as with every named scope taken out."""
+    import contextlib
+    import jax
+    off = _without_metadata(_plan_frames_hlo(exact))
+    with obs.tracing():
+        on = _without_metadata(_plan_frames_hlo(exact))
+    assert on == off
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert _without_metadata(_plan_frames_hlo(exact)) == off
 
 
 def test_runtime_emits_spans_under_tracing():
