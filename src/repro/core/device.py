@@ -19,7 +19,8 @@ on a host CPU, hostile to a TPU's vector units. We restructure it:
   through ``repro.core.search``.
 - ``jag_m_heur_device``: the paper's JAG-M-HEUR end-to-end on device: main
   dimension by wide bisection, proportional processor counts, per-stripe
-  cuts by a batched masked probe (vmapped over stripes). Only the O(m) cut
+  cuts by a batched masked probe (vmapped over stripes) whose greedy
+  loop runs as many steps as the fullest stripe has processors. Only the O(m) cut
   vectors ever leave the device — the load matrix stays in HBM, enabling
   the distributed rebalancing the paper's Section 6 calls for.
 
@@ -138,20 +139,54 @@ def optimal_1d_device(p: jnp.ndarray, m: int, *, k: int = 8,
 # masked per-stripe probe (variable processor counts, static shapes)
 
 
+def _masked_step(p, count, L, i, pos):
+    """Greedy step ``i`` of a ``count``-interval probe: advance while
+    ``i < count``; the last live interval (``i == count - 1``) runs to n."""
+    nxt = jnp.where(i < count, _advance(p, pos[None], L)[0], pos)
+    return jnp.where(i == count - 1, p.shape[0] - 1, nxt)
+
+
 def _probe_cuts_masked(p: jnp.ndarray, m_max: int, count: jnp.ndarray,
-                       L: jnp.ndarray) -> jnp.ndarray:
-    """Cuts (m_max+1,) using only ``count`` intervals; rest collapse at n."""
+                       L: jnp.ndarray, steps: jnp.ndarray) -> jnp.ndarray:
+    """Cuts (m_max+1,) using only ``count`` intervals; rest collapse at n.
+
+    The greedy runs ``steps`` (traced, ``count <= steps <= m_max``) steps,
+    not m_max: the buffer starts at n, so entries past ``count`` are n
+    without being visited.
+    """
     n = p.shape[0] - 1
 
-    def step(carry, i):
-        pos = carry
-        nxt = jnp.where(i < count, _advance(p, pos[None], L)[0], pos)
-        nxt = jnp.where(i == count - 1, n, nxt)  # last live interval: to end
-        return nxt, nxt
+    def step(i, carry):
+        pos, cuts = carry
+        nxt = _masked_step(p, count, L, i, pos)
+        return nxt, cuts.at[i + 1].set(nxt)
 
-    _, cuts = jax.lax.scan(step, jnp.int32(0),
-                           jnp.arange(m_max, dtype=jnp.int32))
-    return jnp.concatenate([jnp.zeros(1, jnp.int32), cuts])
+    cuts0 = jnp.full(m_max + 1, n, jnp.int32).at[0].set(0)
+    _, cuts = jax.lax.fori_loop(jnp.int32(0), steps, step,
+                                (jnp.int32(0), cuts0))
+    return cuts
+
+
+def _probe_bottleneck_masked(p: jnp.ndarray, m_max: int, count: jnp.ndarray,
+                             L: jnp.ndarray,
+                             steps: jnp.ndarray) -> jnp.ndarray:
+    """``_stripe_bottleneck(p, _probe_cuts_masked(...))`` without the cuts.
+
+    The same float subtractions, folded into a running max; the m_max -
+    count intervals never visited are n..n, load 0 (a float32 stripe
+    prefix need not be monotone, so no interval is assumed >= 0).
+    """
+    worst0 = jnp.where(count < m_max, 0, -jnp.inf).astype(p.dtype)
+
+    def step(i, carry):
+        pos, worst = carry
+        nxt = _masked_step(p, count, L, i, pos)
+        load = jnp.take(p, nxt) - jnp.take(p, pos)
+        return nxt, jnp.maximum(worst, load)
+
+    _, worst = jax.lax.fori_loop(jnp.int32(0), steps, step,
+                                 (jnp.int32(0), worst0))
+    return worst
 
 
 def _stripe_bottleneck(p, cuts):
@@ -207,6 +242,9 @@ def jag_m_heur_device_impl(gamma: jnp.ndarray, *, P: int, m: int, k: int = 8,
         counts, _ = jax.lax.scan(give_leftover, counts, None, length=P)
 
     m_max = m - P + 1
+    # the greedy probes run as many steps as the fullest stripe has
+    # processors (all m/P when the rows balance), not m_max
+    steps = jnp.max(counts)
 
     def stripe_optimal(p, count):
         n = p.shape[0] - 1
@@ -217,14 +255,14 @@ def jag_m_heur_device_impl(gamma: jnp.ndarray, *, P: int, m: int, k: int = 8,
 
         def feasible(Ls):
             def feas_one(L):
-                cuts = _probe_cuts_masked(p, m_max, count, L)
-                return _stripe_bottleneck(p, cuts) <= L
+                return _probe_bottleneck_masked(p, m_max, count, L,
+                                                steps) <= L
 
             return jax.vmap(feas_one)(Ls)
 
         _, hi_f = wide_bisect_device(feasible, lo, hi, k=k, rounds=rounds,
                                      dtype=p.dtype)
-        cuts = _probe_cuts_masked(p, m_max, count, hi_f)
+        cuts = _probe_cuts_masked(p, m_max, count, hi_f, steps)
         return cuts, _stripe_bottleneck(p, cuts)
 
     with jax.named_scope("heur.stripes"):
@@ -881,9 +919,11 @@ def jag_m_opt_device_impl(gamma: jnp.ndarray, *, m: int, k: int = 7):
                                 jnp.where(jnp.arange(m) < n_stripes,
                                           ends, n1).astype(jnp.int32)])
 
+    steps = jnp.max(counts)
+
     def stripe_cuts(b, e, x):
         p_s = _stripe_row(gamma, b, e)
-        cuts = _probe_cuts_masked(p_s, m, x, L)
+        cuts = _probe_cuts_masked(p_s, m, x, L, steps)
         cuts = jnp.where(x > 0, cuts, _collapse_cuts(n2, m))
         bott = jnp.max(_cut_loads(p_s, cuts))
         return cuts, jnp.where(x > 0, bott, jnp.zeros_like(bott))

@@ -44,6 +44,10 @@ _FIELDS = (
     # returned iteration/projection counts — jit can't bump Python ints)
     "sgorp_iterations",   # while_loop iterations executed
     "sgorp_projections",  # iterations whose integer projection moved
+    # JAG-M-HEUR stripe probes (core.device; rebalance.planner reads the
+    # per-frame processor counts its host Plans already carry)
+    "heur_probe_steps",         # greedy steps run: max(counts) per frame
+    "heur_probe_steps_static",  # a static m - P + 1 loop's steps per frame
     # serving (serve.batcher / serve.queue / serve.simulate)
     "serve_plans",
     "serve_replans",

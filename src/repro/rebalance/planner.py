@@ -45,6 +45,7 @@ from repro.backend import use_pallas_default
 from repro.core import device
 from repro.kernels.sat import ops as sat_ops
 from repro.obs import trace as _trace
+from repro.obs.counters import C as _C
 from repro.rebalance.policy import replan_mode
 
 __all__ = ["ingest_stage", "sat_stage", "partition_stage", "plan_frames",
@@ -378,6 +379,17 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
     return out
 
 
+def _count_probe_steps(plans, *, P: int, m: int) -> None:
+    """Bump the JAG-M-HEUR stripe-probe step counters from host Plans.
+
+    Each frame's greedy probes run ``max(counts)`` steps where a static
+    loop would run ``m - P + 1``; the counts are already on the host, so
+    this costs no device sync and no extra copy.
+    """
+    _C.heur_probe_steps += sum(int(pl.counts.max()) for pl in plans)
+    _C.heur_probe_steps_static += len(plans) * (m - P + 1)
+
+
 # ---------------------------------------------------------------------------
 # lazy per-slice consumption
 
@@ -437,6 +449,8 @@ def plan_iter(frames, *, P: int, m: int, mesh=None,
         # read) — its span width is the wait the policy loop actually saw
         with _trace.span("planner.collect", t0=t0, t1=t1):
             plans = batch_device.unstack_plans(batched, shape)
+        if not exact:
+            _count_probe_steps(plans, P=P, m=m)
         yield from plans
 
 
@@ -449,4 +463,7 @@ def plan_host(frames, *, P: int, m: int, mesh=None, k: int = 8,
     batched = plan_stream(frames, P=P, m=m, mesh=mesh, k=k, rounds=rounds,
                           gamma_dtype=gamma_dtype, use_pallas=use_pallas,
                           interpret=interpret, exact=exact)
-    return batch_device.unstack_plans(batched, tuple(frames.shape[1:]))
+    plans = batch_device.unstack_plans(batched, tuple(frames.shape[1:]))
+    if not exact:
+        _count_probe_steps(plans, P=P, m=m)
+    return plans

@@ -389,6 +389,29 @@ def test_serve_counters_tick():
     assert C.serve_queue_peak >= 13
 
 
+def test_heur_probe_step_counters():
+    """``heur_probe_steps`` reads each frame's max(counts), summed;
+    ``heur_probe_steps_static`` reads T * (m - P + 1), from plan_iter and
+    plan_host alike; exact calls add nothing."""
+    rng = np.random.default_rng(7)
+    frames = rng.integers(1, 50, (3, 24, 32)).astype(np.float32)
+    frames[0, 5] *= 2000   # one stripe takes m - P + 1 processors
+    frames[1, [2, 17]] *= 40
+    P, m, T = 4, 16, frames.shape[0]
+    C.reset()
+    plans = list(planner.plan_iter(frames, P=P, m=m, slice_size=T))
+    maxes = [int(pl.counts.max()) for pl in plans]
+    assert len(set(maxes)) == T
+    assert C.heur_probe_steps == sum(maxes)
+    assert C.heur_probe_steps_static == T * (m - P + 1)
+    planner.plan_host(frames, P=P, m=m)
+    assert C.heur_probe_steps == 2 * sum(maxes)
+    assert C.heur_probe_steps_static == 2 * T * (m - P + 1)
+    C.reset()
+    list(planner.plan_iter(frames.astype(np.int32), P=P, m=m, exact=True))
+    assert C.heur_probe_steps == C.heur_probe_steps_static == 0
+
+
 # ---------------------------------------------------------------------------
 # benchmark helpers + demo script
 
