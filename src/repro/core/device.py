@@ -555,32 +555,49 @@ def _stripe_row(gamma: jnp.ndarray, b, e) -> jnp.ndarray:
     return jnp.take(gamma, e, axis=0) - jnp.take(gamma, b, axis=0)
 
 
+def _onepass_step(q: jnp.ndarray, pos, v, target):
+    """One greedy step over a non-decreasing row ``q``, without a search.
+
+    ``v`` is ``q[pos]``.  The largest index whose value is <= ``target``
+    is the count of such entries minus one (q is sorted), clipped to
+    ``[pos, len(q) - 1]`` as ``searchsorted(q, target, "right") - 1``
+    was; the value there is the largest entry <= ``target``, or ``v``
+    when the step sticks.  One compare and two reductions over the row:
+    no dependent lookups, no gather.
+    """
+    le = q <= target
+    nxt = jnp.clip(jnp.sum(le, dtype=jnp.int32) - 1, pos, q.shape[0] - 1)
+    return nxt, jnp.max(jnp.where(le, q, v))
+
+
 def _stripe_fits(gamma: jnp.ndarray, b, e, L, Q: int,
                  sp_slice: jnp.ndarray | None = None):
     """Does stripe [b, e) pack into <= Q column intervals of load <= L?
 
     Greedy maximal extension over the stripe's column prefix (exact for
-    the monotone objective).  With ``sp_slice`` ((Q,) speeds) position q
-    packs at most ``L * sp_slice[q]`` and dead positions are skipped —
-    the device twin of ``oned.probe_count(speeds=...) <= Q``.
+    the monotone objective), Q :func:`_onepass_step` steps carrying the
+    position and the prefix value there.  With ``sp_slice`` ((Q,)
+    speeds) position q packs at most ``L * sp_slice[q]`` and dead
+    positions are skipped — the device twin of
+    ``oned.probe_count(speeds=...) <= Q``.
     """
     q = _stripe_row(gamma, b, e)
     n2 = q.shape[0] - 1
+    init = (jnp.int32(0), q[0])
     if sp_slice is None:
-        def step(pos, _):
-            target = jnp.take(q, pos) + L
-            nxt = jnp.searchsorted(q, target, side="right") - 1
-            return jnp.clip(nxt, pos, n2), None
+        def step(carry, _):
+            pos, v = carry
+            return _onepass_step(q, pos, v, v + L), None
 
-        pos, _ = jax.lax.scan(step, jnp.int32(0), None, length=Q)
+        (pos, _), _ = jax.lax.scan(step, init, None, length=Q)
     else:
-        def step(pos, sp_k):
-            target = jnp.take(q, pos) + L * sp_k
-            nxt = jnp.searchsorted(q, target, side="right") - 1
-            nxt = jnp.clip(nxt, pos, n2)
-            return jnp.where(sp_k > 0, nxt, pos), None
+        def step(carry, sp_k):
+            pos, v = carry
+            # a dead position (sp_k == 0) targets v itself: w is v
+            nxt, w = _onepass_step(q, pos, v, v + L * sp_k)
+            return (jnp.where(sp_k > 0, nxt, pos), w), None
 
-        pos, _ = jax.lax.scan(step, jnp.int32(0), sp_slice)
+        (pos, _), _ = jax.lax.scan(step, init, sp_slice)
     return pos == n2
 
 

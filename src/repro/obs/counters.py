@@ -48,6 +48,9 @@ _FIELDS = (
     # per-frame processor counts its host Plans already carry)
     "heur_probe_steps",         # greedy steps run: max(counts) per frame
     "heur_probe_steps_static",  # a static m - P + 1 loop's steps per frame
+    # exact JAG-PQ-OPT row probe (core.device._onepass_step), bumped by
+    # rebalance.planner.plan_stream for every frame it plans exactly
+    "exact_row_onepass_frames",
     # serving (serve.batcher / serve.queue / serve.simulate)
     "serve_plans",
     "serve_replans",

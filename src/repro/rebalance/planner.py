@@ -358,6 +358,10 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
                               gamma_dtype=gamma_dtype,
                               use_pallas=use_pallas, interpret=interpret)
     _check_finite(frames, 0, frames.shape[0], what="plan_stream")
+    if exact:
+        # every exact frame's row probe takes the one-pass greedy step
+        # (device._onepass_step); plan_iter and plan_host count here too
+        _C.exact_row_onepass_frames += frames.shape[0]
     gamma_dtype = resolve_gamma_dtype(gamma_dtype, exact=exact)
     if mesh is None:
         return batch_device.plan_stream(

@@ -412,6 +412,28 @@ def test_heur_probe_step_counters():
     assert C.heur_probe_steps == C.heur_probe_steps_static == 0
 
 
+def test_exact_row_onepass_frame_counter():
+    """``exact_row_onepass_frames`` reads the frames planned by exact
+    plan_stream, plan_iter (every slice) and plan_host calls; heuristic
+    calls add nothing."""
+    rng = np.random.default_rng(17)
+    frames = rng.integers(0, 50, (5, 16, 24)).astype(np.int32)
+    P, m, T = 4, 12, frames.shape[0]
+    C.reset()
+    planner.plan_stream(frames, P=P, m=m, exact=True)
+    assert C.exact_row_onepass_frames == T
+    plans = list(planner.plan_iter(frames, P=P, m=m, slice_size=2,
+                                   exact=True))
+    assert len(plans) == T and C.exact_row_onepass_frames == 2 * T
+    planner.plan_host(frames[:3], P=P, m=m, exact=True)
+    assert C.exact_row_onepass_frames == 2 * T + 3
+    C.reset()
+    planner.plan_stream(frames, P=P, m=m)
+    list(planner.plan_iter(frames, P=P, m=m))
+    planner.plan_host(frames, P=P, m=m)
+    assert C.exact_row_onepass_frames == 0
+
+
 # ---------------------------------------------------------------------------
 # benchmark helpers + demo script
 
