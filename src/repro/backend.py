@@ -1,21 +1,23 @@
 """Platform-resolved execution defaults and the persistent compile cache.
 
-Every Pallas kernel on the planning path takes two knobs, ``use_pallas``
-and ``interpret``.  Callers leave both at ``None`` and they resolve here,
-from the platform the computation will run on:
+The platform picks the kernel.  No function on the planning path takes
+a kernel choice: each place that can run a Pallas kernel asks
+:func:`use_pallas_default` where it calls it, and each kernel wrapper
+asks :func:`pallas_interpret_default` for Pallas's interpret mode:
 
-- on a TPU the kernels run compiled (``use_pallas=True``,
-  ``interpret=False``) — the SAT stage and the exact solver's probe are
-  the shipped kernels, never the jnp fallback or the interpreter;
+- on a TPU the kernels run compiled — the SAT stage and the exact
+  solver's probe are the shipped kernels, never the jnp fallback or the
+  interpreter;
 - anywhere else the planner keeps its jnp path and any kernel that is
   called explicitly runs in interpret mode.
 
 ``JAX_PALLAS_INTERPRET=1`` (``=0``) forces interpret mode on (off) for
-every kernel, the CPU test suite's escape hatch.  The platform is that of
-``jax.default_device`` when one is set (so ``with
-jax.default_device(jax.devices("cpu")[0])`` runs the CPU chain in a TPU
-process), else the default backend's; both are part of jit's cache key,
-so resolving at trace time is safe.
+every kernel.  The platform is that of ``jax.default_device`` when one
+is set (so ``with jax.default_device(jax.devices("cpu")[0])`` runs the
+CPU chain in a TPU process), else the default backend's; both are part
+of jit's cache key, so resolving at trace time is safe.  Tests that force
+the kernels off the chip patch :func:`use_pallas_default` where it is
+called and clear jit's caches around the patch.
 """
 from __future__ import annotations
 

@@ -463,9 +463,7 @@ def _exact_1d_bounds_int(p: jnp.ndarray, m: int):
 
 def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
                               speeds: jnp.ndarray | None = None, *,
-                              k: int = 15,
-                              use_pallas_probe: bool | None = None,
-                              interpret: bool | None = None):
+                              k: int = 15):
     """Unjitted body of :func:`nicol_optimal_device`.
 
     Returns ``(cuts (m+1,) int32, bottleneck scalar)``.  Integer ``p``
@@ -475,10 +473,9 @@ def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
     the relative-load objective (always float; pass the vector already
     normalized by ``search.normalize_speeds`` — uniform vectors should
     be dropped to ``None`` host-side to keep the homogeneous path
-    bit-identical).  ``use_pallas_probe`` routes the homogeneous
-    feasibility probe through the ``kernels.probe`` Pallas kernel instead
-    of the jnp scan; ``None`` resolves it, and ``interpret``, from the
-    platform (:mod:`repro.backend`: the compiled kernel on a TPU).
+    bit-identical).  On a TPU the homogeneous feasibility probe is the
+    ``kernels.probe`` Pallas kernel, elsewhere the jnp scan
+    (:mod:`repro.backend`).
     """
     n = p.shape[0] - 1
     if speeds is not None:
@@ -511,15 +508,12 @@ def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
         lo = jnp.maximum(total / m, maxel)
         hi = total / m + maxel
 
-    if use_pallas_probe is None:
-        use_pallas_probe = use_pallas_default()
-    if use_pallas_probe:
+    if use_pallas_default():
         from repro.kernels.probe import ops as probe_ops
 
         def feasible(cand):
             cnt = probe_ops.probe_counts_impl(
-                p[None, :], cand[None, :].astype(p.dtype), m,
-                use_pallas=True, interpret=interpret)
+                p[None, :], cand[None, :].astype(p.dtype), m)
             return cnt[0] <= m
     else:
         def feasible(cand):
@@ -533,9 +527,8 @@ def nicol_optimal_device_impl(p: jnp.ndarray, m: int,
     return cuts, jnp.max(_cut_loads(p, cuts))
 
 
-nicol_optimal_device = jax.jit(
-    nicol_optimal_device_impl,
-    static_argnames=("m", "k", "use_pallas_probe", "interpret"))
+nicol_optimal_device = jax.jit(nicol_optimal_device_impl,
+                               static_argnames=("m", "k"))
 nicol_optimal_device.__doc__ = (
     "Exact 1D partition fully on device (jitted).\n"
     + nicol_optimal_device_impl.__doc__.split("\n", 1)[1])
@@ -668,9 +661,7 @@ def _collapse_cuts(n2: int, m: int) -> jnp.ndarray:
 
 
 def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
-                           speeds: jnp.ndarray | None = None, k: int = 15,
-                           use_pallas_probe: bool | None = None,
-                           interpret: bool | None = None):
+                           speeds: jnp.ndarray | None = None, k: int = 15):
     """Unjitted body of :func:`jag_pq_opt_device` (JAG-PQ-OPT on device).
 
     gamma: (n1+1, n2+1) device prefix sums, 'hor' orientation (transpose
@@ -685,12 +676,10 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
     feasible integer before realizing with the host probe's collapse
     semantics.  ``speeds`` ((P*Q,) pre-normalized) switches everything to
     relative load (always float; bottleneck matches the host hetero
-    solver to its 1e-9 tolerance).  ``use_pallas_probe`` routes the
-    per-stripe column feasibility probes through the ``kernels.probe``
-    Pallas kernel — with a Pallas SAT stage in front this is the fused
-    SAT -> probe -> cut path, no host round-trip anywhere.  ``None``
-    resolves it, and ``interpret``, from the platform
-    (:mod:`repro.backend`: the compiled kernel on a TPU).
+    solver to its 1e-9 tolerance).  On a TPU the per-stripe column
+    feasibility probes are the ``kernels.probe`` Pallas kernel
+    (:mod:`repro.backend`) — with a Pallas SAT stage in front this is the
+    fused SAT -> probe -> cut path, no host round-trip anywhere.
 
     Without ``speeds`` the four stages run under ``jax.named_scope``s,
     which name the ``op_name`` of their instructions in the compiled HLO:
@@ -767,15 +756,12 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
             else (jnp.maximum(p_s[n2] / Q, jnp.max(jnp.diff(p_s))),
                   p_s[n2] / Q + jnp.max(jnp.diff(p_s))))(sm)
 
-        if use_pallas_probe is None:
-            use_pallas_probe = use_pallas_default()
-        if use_pallas_probe:
+        if use_pallas_default():
             from repro.kernels.probe import ops as probe_ops
 
             def sfeasible(cand):
-                cnt = probe_ops.probe_counts_impl(
-                    sm, cand.astype(sm.dtype), Q,
-                    use_pallas=True, interpret=interpret)
+                cnt = probe_ops.probe_counts_impl(sm, cand.astype(sm.dtype),
+                                                  Q)
                 return cnt <= Q
         else:
             def sfeasible(cand):
@@ -797,9 +783,8 @@ def jag_pq_opt_device_impl(gamma: jnp.ndarray, *, P: int, Q: int,
         return row_cuts, counts, col_cuts, jnp.max(bots)
 
 
-jag_pq_opt_device = jax.jit(
-    jag_pq_opt_device_impl,
-    static_argnames=("P", "Q", "k", "use_pallas_probe", "interpret"))
+jag_pq_opt_device = jax.jit(jag_pq_opt_device_impl,
+                            static_argnames=("P", "Q", "k"))
 jag_pq_opt_device.__doc__ = ("JAG-PQ-OPT fully on device (jitted).\n"
                              + jag_pq_opt_device_impl.__doc__
                              .split("\n", 1)[1])

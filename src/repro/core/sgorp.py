@@ -20,7 +20,7 @@ iteration that jit-compiles once and ``vmap``s over frames.  Convergence
 is monitored on the *best projected integer cuts seen*: the loop exits
 after ``patience`` non-improving iterations, and because iteration 0
 evaluates the warm-start cuts themselves, the result can never be worse
-than its warm start — the refiner's contract with the benchmarks.
+than its warm start — the refiner's contract, which the tests check.
 
 The warm start is the d-axis rectilinear heuristic: an optimal 1D
 partition of each axis' margin prefix (``device.optimal_1d_device``),
@@ -198,23 +198,19 @@ def sgorp_plan_impl(gamma, speed_grid=None, *, grid, max_iters: int = 256,
 
 def sgorp_plan_3d_impl(frames, speed_grid=None, *, grid,
                        max_iters: int = 256, patience: int = 32,
-                       k: int = 8, rounds: int = 8, gamma_dtype=None,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None):
+                       k: int = 8, rounds: int = 8, gamma_dtype=None):
     """The batched 3D planning chain: (T, n1, n2, n3) frames -> stacked
     rectilinear cuts.  ingest -> Gamma3 (``kernels/sat`` rank-3 path) ->
     vmapped warm start + SGORP refine — one jit boundary, so the sharded
-    planner traces it like the 2D chain.  ``use_pallas=None`` takes the
-    compiled Gamma3 kernel on a TPU and the oracle elsewhere
-    (:mod:`repro.backend`).  Returns (cuts1 (T, p1+1), cuts2 (T, p2+1),
+    planner traces it like the 2D chain.  The platform picks the Gamma3
+    build (:mod:`repro.backend`): the compiled kernel on a TPU, the
+    oracle elsewhere.  Returns (cuts1 (T, p1+1), cuts2 (T, p2+1),
     cuts3 (T, p3+1), Lmax (T,), iters (T,), projections (T,))."""
     from repro.backend import use_pallas_default
     from repro.kernels.sat import ops as sat_ops
-    if use_pallas is None:
-        use_pallas = use_pallas_default()
     gamma_dtype = jnp.float32 if gamma_dtype is None else gamma_dtype
     g = sat_ops.gamma3_impl(frames.astype(gamma_dtype),
-                            use_pallas=use_pallas, interpret=interpret)
+                            use_pallas=use_pallas_default())
 
     def one(gamma):
         cuts, L, it, pr = sgorp_plan_impl(gamma, speed_grid, grid=grid,

@@ -12,28 +12,19 @@ from .rectload import jagged_loads_pallas, load_dtype
 from .ref import jagged_loads_ref
 
 
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
 def jagged_loads(gamma: jnp.ndarray, row_cuts: jnp.ndarray,
-                 col_cuts: jnp.ndarray, *, use_pallas: bool = True,
-                 interpret: bool | None = None) -> jnp.ndarray:
+                 col_cuts: jnp.ndarray, *,
+                 use_pallas: bool = True) -> jnp.ndarray:
     """Rectangle loads; accepts 2D Gamma or a leading-frame-axis batch.
 
     Loads are int32 for an integer Gamma and f32 otherwise.
-    ``interpret=None`` resolves via
-    :func:`repro.backend.pallas_interpret_default`; resolution happens
-    outside the jit so the cache key carries the concrete mode.
+    ``use_pallas`` selects the kernel or its jnp oracle; the kernel's
+    interpret mode resolves via
+    :func:`repro.backend.pallas_interpret_default`.
     """
-    if interpret is None:
-        interpret = pallas_interpret_default()
-    return _jagged_loads(gamma, row_cuts, col_cuts, use_pallas=use_pallas,
-                         interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def _jagged_loads(gamma: jnp.ndarray, row_cuts: jnp.ndarray,
-                  col_cuts: jnp.ndarray, *, use_pallas: bool,
-                  interpret: bool) -> jnp.ndarray:
     if not use_pallas:
         return jagged_loads_ref(gamma.astype(load_dtype(gamma)), row_cuts,
                                 col_cuts)
     return jagged_loads_pallas(gamma, row_cuts, col_cuts,
-                               interpret=interpret)
+                               interpret=pallas_interpret_default())

@@ -2,9 +2,7 @@
 
 The hot paths (``core.search``, ``core.stripecache``, ``core.oned``) bump
 attributes on :data:`C` unconditionally — a Python attribute ``+= 1`` costs
-tens of nanoseconds, which is invisible next to the numpy calls it counts
-(the dedicated overhead bench ``benchmarks/bench_obs.py`` gates the whole
-instrumented stack, counters included, at <3% on ``jag-pq-opt.m1000``).
+tens of nanoseconds, which is invisible next to the numpy calls it counts.
 There is deliberately no enable flag and no function-call indirection on
 the increment path: a branch would cost as much as the add.
 

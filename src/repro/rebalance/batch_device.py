@@ -38,24 +38,21 @@ __all__ = ["Plan", "gamma_batch", "jag_m_heur_batch", "plan_stream",
            "unstack_plans"]
 
 
-@functools.partial(jax.jit, static_argnames=("gamma_dtype", "use_pallas",
-                                             "interpret"))
-def gamma_batch(frames: jnp.ndarray, *, gamma_dtype=jnp.float32,
-                use_pallas: bool | None = None,
-                interpret: bool | None = None) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("gamma_dtype",))
+def gamma_batch(frames: jnp.ndarray, *,
+                gamma_dtype=jnp.float32) -> jnp.ndarray:
     """Gamma for every frame: (T, n1, n2) loads -> (T, n1+1, n2+1) prefixes.
 
     The jitted standalone form of the planner's ingest + SAT stages.
     Frames are cast to ``gamma_dtype`` *before* the scan so accumulation
     happens in that dtype (f32 saturates above 2**24 total load; pass
-    ``jnp.float64`` with x64 enabled for large integer loads).
-    ``use_pallas=None`` resolves from the platform (:mod:`repro.backend`):
-    on a TPU the blocked Pallas kernel with a leading batch grid axis,
-    elsewhere the pure-jnp SAT oracle.
+    ``jnp.float64`` with x64 enabled for large integer loads).  The
+    platform picks the SAT (:mod:`repro.backend`): on a TPU the blocked
+    Pallas kernel with a leading batch grid axis, elsewhere the pure-jnp
+    oracle.
     """
     return planner.sat_stage(
-        planner.ingest_stage(frames, gamma_dtype=gamma_dtype),
-        use_pallas=use_pallas, interpret=interpret)
+        planner.ingest_stage(frames, gamma_dtype=gamma_dtype))
 
 
 @functools.partial(jax.jit,
@@ -73,12 +70,9 @@ def jag_m_heur_batch(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
 
 
 @functools.partial(jax.jit, static_argnames=("P", "m", "k", "rounds",
-                                             "gamma_dtype", "use_pallas",
-                                             "interpret", "exact"))
+                                             "gamma_dtype", "exact"))
 def plan_stream(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
-                rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool | None = None,
-                interpret: bool | None = None, exact: bool = False):
+                rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """SAT + partitioner for a whole (T, n1, n2) stream under one jit.
 
     Composes the planner's *unjitted* stage bodies directly, so the fused
@@ -89,11 +83,11 @@ def plan_stream(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
     ``repro.rebalance.planner.plan_stream(mesh=...)``.  ``exact=True``
     swaps in the exact device JAG-PQ-OPT (needs ``m % P == 0``; cuts
     bit-identical to ``jagged.jag_pq_opt(orient='hor')`` per frame).
+    The kernels are the platform's choice, made at trace time
+    (:mod:`repro.backend`), so they are not part of the signature.
     """
     return planner.plan_frames(frames, P=P, m=m, k=k, rounds=rounds,
-                               gamma_dtype=gamma_dtype,
-                               use_pallas=use_pallas, interpret=interpret,
-                               exact=exact)
+                               gamma_dtype=gamma_dtype, exact=exact)
 
 
 # ---------------------------------------------------------------------------

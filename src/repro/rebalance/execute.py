@@ -106,22 +106,19 @@ def _device_gammas(*ws: np.ndarray) -> jnp.ndarray:
     return jnp.asarray(gs, dtype=jnp.int32 if exact else jnp.float32)
 
 
-def plan_rect_loads(plan: Plan, weights=None, *,
-                    interpret: bool | None = None) -> np.ndarray:
+def plan_rect_loads(plan: Plan, weights=None) -> np.ndarray:
     """(m,) per-rectangle loads of ``plan`` computed on device via the
     rectload kernel (host twin: :meth:`Plan.loads` on the frame's Gamma).
     """
     w, _ = _weight_array(plan, weights)
     g = _device_gammas(w)[0]
     out = jagged_loads(g, jnp.asarray(plan.row_cuts, dtype=jnp.int32),
-                       jnp.asarray(plan._live_col_cuts(), dtype=jnp.int32),
-                       interpret=interpret)
+                       jnp.asarray(plan._live_col_cuts(), dtype=jnp.int32))
     return _live_loads(plan, np.asarray(out))
 
 
 def execute_migration(old: Plan, new: Plan, weights=None, *,
-                      devices=None, interpret: bool | None = None
-                      ) -> MigrationReceipt:
+                      devices=None) -> MigrationReceipt:
     """Move every owner-changed cell's weight to its new processor's
     device and measure what arrived.  See the module docstring for the
     exactness contract against :mod:`repro.rebalance.migrate`.
@@ -172,7 +169,7 @@ def execute_migration(old: Plan, new: Plan, weights=None, *,
         cc = jnp.broadcast_to(
             jnp.asarray(new._live_col_cuts(), dtype=jnp.int32),
             (2,) + new.col_cuts.shape)
-        both = np.asarray(jagged_loads(stack, rc, cc, interpret=interpret))
+        both = np.asarray(jagged_loads(stack, rc, cc))
         rect_loads = _live_loads(new, both[0])
         rect_received = _live_loads(new, both[0] - both[1])
 

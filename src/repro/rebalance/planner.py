@@ -22,6 +22,10 @@ are **bit-identical** to the single-device reference on 1-, 2- and
 device count — the ragged tail is zero-padded on device and trimmed from
 the result).
 
+The platform picks the kernels (:mod:`repro.backend`): the SAT stage
+and the exact solver's column probe run their Pallas kernels on a TPU
+and their jnp oracles elsewhere.  No function here takes that choice.
+
 ``iter_plan_slices`` / ``plan_iter`` expose the stream lazily: every
 slice is dispatched up front (jax dispatch is asynchronous), so a policy
 loop consuming slice ``i`` overlaps with the devices still planning
@@ -113,36 +117,28 @@ def ingest_stage(frames: jnp.ndarray, *,
     return frames.astype(gamma_dtype)
 
 
-def sat_stage(frames: jnp.ndarray, *, use_pallas: bool | None = None,
-              interpret: bool | None = None) -> jnp.ndarray:
+def sat_stage(frames: jnp.ndarray) -> jnp.ndarray:
     """SAT build: (T, n1, n2) frames -> (T, n1+1, n2+1) Gammas.
 
     Both backends take the batch natively — the Pallas kernel's leading
     batch grid axis (so the blocked path lowers under the sharded trace
     instead of falling back to the jnp oracle) and the oracle's
-    trailing-axes cumsum.  ``use_pallas=None`` resolves from the platform
-    (:mod:`repro.backend`): the compiled kernel on a TPU, the oracle
-    elsewhere.
+    trailing-axes cumsum.  The platform picks one (:mod:`repro.backend`):
+    the compiled kernel on a TPU, the oracle elsewhere.
     """
-    if use_pallas is None:
-        use_pallas = use_pallas_default()
-    return sat_ops.gamma_impl(frames, use_pallas=use_pallas,
-                              interpret=interpret)
+    return sat_ops.gamma_impl(frames, use_pallas=use_pallas_default())
 
 
 def partition_stage(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
-                    rounds: int = 8, gamma_dtype=None, exact: bool = False,
-                    use_pallas: bool | None = None,
-                    interpret: bool | None = None):
+                    rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """Partition: vmapped partitioner over the (T, n1+1, n2+1) Gamma batch.
 
     ``exact=False`` (default) runs JAG-M-HEUR; ``exact=True`` runs the
     device-native exact JAG-PQ-OPT (``device.jag_pq_opt_device_impl``,
     ``Q = m // P`` intervals per stripe — cuts bit-identical to the host
-    ``jagged.jag_pq_opt(orient='hor')``), with ``use_pallas`` routing its
-    column probes through the fused ``kernels.probe`` kernel (``None``:
-    on a TPU).  Returns (row_cuts (T, P+1), counts (T, P),
-    col_cuts (T, P, *), Lmax (T,)).
+    ``jagged.jag_pq_opt(orient='hor')``), whose column probes take the
+    fused ``kernels.probe`` kernel on a TPU.  Returns (row_cuts (T, P+1),
+    counts (T, P), col_cuts (T, P, *), Lmax (T,)).
     """
     if exact:
         if m % P != 0:
@@ -150,8 +146,7 @@ def partition_stage(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
                 f"exact planning needs m divisible by P (m={m}, P={P}): "
                 f"the exact device solver is the P x Q form")
         fn = functools.partial(device.jag_pq_opt_device_impl, P=P, Q=m // P,
-                               k=max(k, 2), use_pallas_probe=use_pallas,
-                               interpret=interpret)
+                               k=max(k, 2))
     else:
         fn = functools.partial(device.jag_m_heur_device_impl, P=P, m=m, k=k,
                                rounds=rounds, gamma_dtype=gamma_dtype)
@@ -159,9 +154,7 @@ def partition_stage(gammas: jnp.ndarray, *, P: int, m: int, k: int = 8,
 
 
 def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
-                rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool | None = None,
-                interpret: bool | None = None, exact: bool = False):
+                rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """The full unjitted chain: ingest -> SAT -> partition.
 
     Every intermediate (frames, Gammas) stays on the executing device;
@@ -169,9 +162,9 @@ def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
     collect" stage is whoever fetches them (the host, or the all-gather
     implicit in reading a sharded result).  ``exact=True`` swaps the
     partition stage for the exact device JAG-PQ-OPT and defaults the
-    accumulator to int32 (see :func:`resolve_gamma_dtype`) — with
-    ``use_pallas`` this is the fused SAT -> probe -> cut path, no host
-    round-trip between integral image and cuts.
+    accumulator to int32 (see :func:`resolve_gamma_dtype`) — on a TPU
+    this is the fused SAT -> probe -> cut path, no host round-trip
+    between integral image and cuts.
 
     Each stage runs under a ``jax.named_scope`` (``planner.ingest``,
     ``planner.sat``, ``planner.partition``), which names the ``op_name``
@@ -181,18 +174,15 @@ def plan_frames(frames: jnp.ndarray, *, P: int, m: int, k: int = 8,
     with jax.named_scope("planner.ingest"):
         f = ingest_stage(frames, gamma_dtype=gamma_dtype)
     with jax.named_scope("planner.sat"):
-        g = sat_stage(f, use_pallas=use_pallas, interpret=interpret)
+        g = sat_stage(f)
     with jax.named_scope("planner.partition"):
         return partition_stage(g, P=P, m=m, k=k, rounds=rounds,
-                               gamma_dtype=gamma_dtype, exact=exact,
-                               use_pallas=use_pallas, interpret=interpret)
+                               gamma_dtype=gamma_dtype, exact=exact)
 
 
 def plan_frames_3d(frames: jnp.ndarray, *, grid: tuple[int, ...],
                    max_iters: int = 256, patience: int = 32, k: int = 8,
-                   rounds: int = 8, gamma_dtype=None,
-                   use_pallas: bool | None = None,
-                   interpret: bool | None = None):
+                   rounds: int = 8, gamma_dtype=None):
     """The rank-3 chain: ingest -> 3D SAT -> vmapped SGORP plan.
 
     The volumetric twin of :func:`plan_frames` for ``(T, n1, n2, n3)``
@@ -206,8 +196,7 @@ def plan_frames_3d(frames: jnp.ndarray, *, grid: tuple[int, ...],
     from repro.core import sgorp
     return sgorp.sgorp_plan_3d_impl(
         frames, grid=grid, max_iters=max_iters, patience=patience,
-        k=k, rounds=rounds, gamma_dtype=gamma_dtype,
-        use_pallas=use_pallas, interpret=interpret)
+        k=k, rounds=rounds, gamma_dtype=gamma_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -240,14 +229,12 @@ def _dp_spec(mesh):
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_plan_fn(mesh, P, m, k, rounds, gamma_dtype, use_pallas,
-                     interpret, exact):
+def _sharded_plan_fn(mesh, P, m, k, rounds, gamma_dtype, exact):
     """jit(shard_map(chain)) for one (mesh, signature) — cached so repeat
     calls reuse the compiled executable."""
     spec, _ = _dp_spec(mesh)
     body = functools.partial(plan_frames, P=P, m=m, k=k, rounds=rounds,
-                             gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-                             interpret=interpret, exact=exact)
+                             gamma_dtype=gamma_dtype, exact=exact)
     # every computation is frame-local — no value ever varies across the
     # mesh except through the sharded time axis — so the varying-axes
     # check has nothing to prove; it is off because the solvers' scan and
@@ -258,14 +245,13 @@ def _sharded_plan_fn(mesh, P, m, k, rounds, gamma_dtype, use_pallas,
 
 @functools.lru_cache(maxsize=None)
 def _sharded_plan3d_fn(mesh, grid, max_iters, patience, k, rounds,
-                       gamma_dtype, use_pallas, interpret):
+                       gamma_dtype):
     """jit(shard_map(3D chain)) for one (mesh, signature) — cached like
     :func:`_sharded_plan_fn`."""
     spec, _ = _dp_spec(mesh)
     body = functools.partial(plan_frames_3d, grid=grid, max_iters=max_iters,
                              patience=patience, k=k, rounds=rounds,
-                             gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-                             interpret=interpret)
+                             gamma_dtype=gamma_dtype)
     # frame-local like the 2D chain above, so the check is off likewise
     return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
                                  out_specs=spec, check_vma=False))
@@ -273,9 +259,7 @@ def _sharded_plan3d_fn(mesh, grid, max_iters, patience, k, rounds,
 
 def plan_stream_3d(frames, *, m: int, grid: tuple[int, ...] | None = None,
                    mesh=None, max_iters: int = 256, patience: int = 32,
-                   k: int = 8, rounds: int = 8, gamma_dtype=None,
-                   use_pallas: bool | None = None,
-                   interpret: bool | None = None):
+                   k: int = 8, rounds: int = 8, gamma_dtype=None):
     """SGORP planning for a whole (T, n1, n2, n3) volume stream.
 
     The rank-3 twin of :func:`plan_stream`: ``mesh=None`` runs the whole
@@ -305,8 +289,7 @@ def plan_stream_3d(frames, *, m: int, grid: tuple[int, ...] | None = None,
         fn = jax.jit(functools.partial(
             plan_frames_3d, grid=grid, max_iters=max_iters,
             patience=patience, k=k, rounds=rounds,
-            gamma_dtype=jnp.dtype(gamma_dtype), use_pallas=use_pallas,
-            interpret=interpret))
+            gamma_dtype=jnp.dtype(gamma_dtype)))
         return fn(frames)
     from jax.sharding import NamedSharding
     spec, D = _dp_spec(mesh)
@@ -318,17 +301,14 @@ def plan_stream_3d(frames, *, m: int, grid: tuple[int, ...] | None = None,
                                frames.dtype)])
     fr = jax.device_put(frames, NamedSharding(mesh, spec))
     out = _sharded_plan3d_fn(mesh, grid, max_iters, patience, k, rounds,
-                             jnp.dtype(gamma_dtype), use_pallas,
-                             interpret)(fr)
+                             jnp.dtype(gamma_dtype))(fr)
     if Tpad != T:
         out = jax.tree_util.tree_map(lambda x: x[:T], out)
     return out
 
 
 def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
-                rounds: int = 8, gamma_dtype=None,
-                use_pallas: bool | None = None,
-                interpret: bool | None = None, exact: bool = False):
+                rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """SAT + partitioner for a whole (T, n1, n2) stream.
 
     ``mesh=None`` is the single-device reference (identical to
@@ -355,8 +335,7 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
                 "exact=True has no rank-3 solver; the 3D path plans with "
                 "the SGORP refiner (plan_stream_3d)")
         return plan_stream_3d(frames, m=m, mesh=mesh, k=k, rounds=rounds,
-                              gamma_dtype=gamma_dtype,
-                              use_pallas=use_pallas, interpret=interpret)
+                              gamma_dtype=gamma_dtype)
     _check_finite(frames, 0, frames.shape[0], what="plan_stream")
     if exact:
         # every exact frame's row probe takes the one-pass greedy step
@@ -366,7 +345,7 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
     if mesh is None:
         return batch_device.plan_stream(
             frames, P=P, m=m, k=k, rounds=rounds, gamma_dtype=gamma_dtype,
-            use_pallas=use_pallas, interpret=interpret, exact=exact)
+            exact=exact)
     from jax.sharding import NamedSharding
     spec, D = _dp_spec(mesh)
     T = frames.shape[0]
@@ -377,7 +356,7 @@ def plan_stream(frames, *, P: int, m: int, mesh=None, k: int = 8,
                                frames.dtype)])
     fr = jax.device_put(frames, NamedSharding(mesh, spec))
     out = _sharded_plan_fn(mesh, P, m, k, rounds, jnp.dtype(gamma_dtype),
-                           use_pallas, interpret, exact)(fr)
+                           exact)(fr)
     if Tpad != T:
         out = jax.tree_util.tree_map(lambda x: x[:T], out)
     return out
@@ -400,9 +379,7 @@ def _count_probe_steps(plans, *, P: int, m: int) -> None:
 
 def iter_plan_slices(frames, *, P: int, m: int, mesh=None,
                      slice_size: int | None = None, k: int = 8,
-                     rounds: int = 8, gamma_dtype=None,
-                     use_pallas: bool | None = None,
-                     interpret: bool | None = None, exact: bool = False):
+                     rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """Yield ``(t0, t1, batched_slice)`` over the stream, planned lazily.
 
     All slices are dispatched before the first yield — jax dispatch is
@@ -429,15 +406,13 @@ def iter_plan_slices(frames, *, P: int, m: int, mesh=None,
         with _trace.span("planner.dispatch", slice=i, t0=t0, t1=t1):
             pending.append((t0, t1, plan_stream(
                 frames[t0:t1], P=P, m=m, mesh=mesh, k=k, rounds=rounds,
-                gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-                interpret=interpret, exact=exact)))
+                gamma_dtype=gamma_dtype, exact=exact)))
     yield from pending
 
 
 def plan_iter(frames, *, P: int, m: int, mesh=None,
               slice_size: int | None = None, k: int = 8, rounds: int = 8,
-              gamma_dtype=None, use_pallas: bool | None = None,
-              interpret: bool | None = None, exact: bool = False):
+              gamma_dtype=None, exact: bool = False):
     """Per-frame :class:`~repro.rebalance.batch_device.Plan` iterator.
 
     The lazy flattening of :func:`iter_plan_slices` — what the runtime's
@@ -447,8 +422,7 @@ def plan_iter(frames, *, P: int, m: int, mesh=None,
     shape = tuple(frames.shape[1:])
     for t0, t1, batched in iter_plan_slices(
             frames, P=P, m=m, mesh=mesh, slice_size=slice_size, k=k,
-            rounds=rounds, gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-            interpret=interpret, exact=exact):
+            rounds=rounds, gamma_dtype=gamma_dtype, exact=exact):
         # collect blocks on the slice's device results (the first host
         # read) — its span width is the wait the policy loop actually saw
         with _trace.span("planner.collect", t0=t0, t1=t1):
@@ -459,14 +433,11 @@ def plan_iter(frames, *, P: int, m: int, mesh=None,
 
 
 def plan_host(frames, *, P: int, m: int, mesh=None, k: int = 8,
-              rounds: int = 8, gamma_dtype=None,
-              use_pallas: bool | None = None,
-              interpret: bool | None = None, exact: bool = False):
+              rounds: int = 8, gamma_dtype=None, exact: bool = False):
     """Whole-stream planning to host Plans (one dispatch, no slicing)."""
     from repro.rebalance import batch_device
     batched = plan_stream(frames, P=P, m=m, mesh=mesh, k=k, rounds=rounds,
-                          gamma_dtype=gamma_dtype, use_pallas=use_pallas,
-                          interpret=interpret, exact=exact)
+                          gamma_dtype=gamma_dtype, exact=exact)
     plans = batch_device.unstack_plans(batched, tuple(frames.shape[1:]))
     if not exact:
         _count_probe_steps(plans, P=P, m=m)

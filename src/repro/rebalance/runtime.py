@@ -15,7 +15,7 @@ cut vectors and the owner maps it diffs.
 
 ``compare_policies`` runs several policies over the same precomputed
 candidate plans, which is how the never/always/hysteresis trade-off
-(Fig. 4's motivation) is measured in the benchmarks and tests.
+(Fig. 4's motivation) is measured in the tests.
 """
 from __future__ import annotations
 

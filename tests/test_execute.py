@@ -157,12 +157,11 @@ def test_execute_validates_inputs():
                                   devices=jax.device_count() + 1)
 
 
-def test_execute_interpret_mode_pallas_leg():
+def test_execute_interpret_mode_pallas_leg(pallas_kernels):
     """Force the Pallas interpret path explicitly (the CI interpret leg)."""
     frames = np.asarray(stream.drifting_hotspot(2, 24, 40, seed=4))
     plans = _plans(frames)
-    r = execute.execute_migration(plans[0], plans[1], weights=frames[1],
-                                  interpret=True)
+    r = execute.execute_migration(plans[0], plans[1], weights=frames[1])
     execute.verify_receipt(plans[0], plans[1], frames[1], receipt=r)
 
 

@@ -61,7 +61,8 @@ def test_rectload_matches_ref(n1, n2, P, Q, rng):
 @pytest.mark.parametrize("S,n,K,cap", [
     (1, 1, 1, 1), (3, 17, 5, 4), (8, 128, 16, 7), (5, 300, 3, 12),
 ])
-def test_probe_counts_pallas_matches_ref_and_host(S, n, K, cap, rng):
+def test_probe_counts_pallas_matches_ref_and_host(S, n, K, cap, rng,
+                                                  pallas_kernels):
     """Probe kernel == jnp oracle == the host scalar greedy, including
     the cap+1 infeasible sentinel and all-zero stripes."""
     from repro.core import oned
@@ -75,7 +76,7 @@ def test_probe_counts_pallas_matches_ref_and_host(S, n, K, cap, rng):
     Ls = np.stack([np.linspace(1, max(int(p[s, -1]), 2), K)
                    for s in range(S)]).astype(np.int32)
     got = np.asarray(probe_counts(jnp.asarray(p), jnp.asarray(Ls), cap,
-                                  use_pallas=True, interpret=True))
+                                  use_pallas=True))
     want = np.asarray(probe_counts_ref(jnp.asarray(p), jnp.asarray(Ls),
                                        cap))
     np.testing.assert_array_equal(got, want)
@@ -224,7 +225,8 @@ def test_rectload_int32_ragged_rows_exact(B, n1, n2, P, Q, rng):
 
 @pytest.mark.parametrize("S,n,K,cap", [(2, 4, 9, 3), (4, 129, 8, 32),
                                        (3, 300, 15, 6)])
-def test_probe_ragged_candidates_matches_ref(S, n, K, cap, rng):
+def test_probe_ragged_candidates_matches_ref(S, n, K, cap, rng,
+                                             pallas_kernels):
     """Row lengths off the 128-lane grid and candidate counts off the
     8-sublane grid (the exact path asks for k=8 per stripe)."""
     from repro.kernels.probe import probe_counts, probe_counts_ref
@@ -233,13 +235,13 @@ def test_probe_ragged_candidates_matches_ref(S, n, K, cap, rng):
                        axis=1).astype(np.int32)
     Ls = rng.integers(1, int(p[:, -1].max()) + 2, (S, K)).astype(np.int32)
     got = probe_counts(jnp.asarray(p), jnp.asarray(Ls), cap,
-                       use_pallas=True, interpret=True)
+                       use_pallas=True)
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(probe_counts_ref(jnp.asarray(p), jnp.asarray(Ls), cap)))
 
 
-def test_probe_vmapped_inside_while_loop(rng):
+def test_probe_vmapped_inside_while_loop(rng, pallas_kernels):
     """The exact path's use: the probe kernel as the feasibility test of
     the lockstep column bisection (a ``while_loop``), vmapped over a
     frame stack — same minimal bottlenecks as the jnp probe."""
@@ -255,8 +257,8 @@ def test_probe_vmapped_inside_while_loop(rng):
 
     def solve(ps, use_pallas):
         def feasible(cand):
-            return probe_counts_impl(ps, cand, Q, use_pallas=use_pallas,
-                                     interpret=True) <= Q
+            return probe_counts_impl(ps, cand, Q,
+                                     use_pallas=use_pallas) <= Q
         lo, hi = jax.vmap(lambda q: device._exact_1d_bounds_int(q, Q))(ps)
         return device._wide_bisect_exact_batch(feasible, lo, hi, k=8)
 
@@ -275,6 +277,30 @@ def test_cpu_default_device_resolves_cpu_paths():
     with jax.default_device(jax.devices("cpu")[0]):
         assert backend.platform() == "cpu"
         assert backend.use_pallas_default() is False
+
+
+@pytest.mark.parametrize("module", [
+    "repro.rebalance.planner", "repro.rebalance.batch_device",
+    "repro.rebalance.execute", "repro.core.device", "repro.core.sgorp",
+    "repro.kernels.sat.ops", "repro.kernels.probe.ops",
+    "repro.kernels.rectload.ops",
+])
+def test_planning_path_takes_no_kernel_choice(module):
+    """The platform picks each kernel where it is called: no function of
+    the planning path takes the choice as a parameter, and no kernel
+    wrapper (which keeps ``use_pallas``, kernel or oracle) takes Pallas's
+    interpret mode.  Jitted and cached functions are included."""
+    import importlib
+    import inspect
+    mod = importlib.import_module(module)
+    fns = {n: f for n, f in vars(mod).items()
+           if callable(f) and getattr(f, "__module__", None) == module}
+    knobs = {"interpret"} if module.startswith("repro.kernels.") else \
+        {"use_pallas", "use_pallas_probe", "interpret"}
+    assert fns
+    for name, f in fns.items():
+        found = knobs & set(inspect.signature(f).parameters)
+        assert not found, (module, name, found)
 
 
 def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch,

@@ -435,63 +435,7 @@ def test_exact_row_onepass_frame_counter():
 
 
 # ---------------------------------------------------------------------------
-# benchmark helpers + demo script
-
-
-def test_common_environment_keys():
-    sys.path.insert(0, ROOT)
-    try:
-        from benchmarks import common
-    finally:
-        sys.path.remove(ROOT)
-    env = common.environment()
-    for key in ("python", "platform", "numpy", "xla_flags", "jax"):
-        assert key in env
-    assert env is common.environment()  # cached
-
-
-def test_compare_env_mismatch_helper():
-    sys.path.insert(0, ROOT)
-    try:
-        from benchmarks import compare
-    finally:
-        sys.path.remove(ROOT)
-    base = {"a": {"name": "a", "env": {"jax": "0.4.1", "device_count": 1}}}
-    new = {"a": {"name": "a", "env": {"jax": "0.5.0", "device_count": 1}}}
-    diffs = compare.env_mismatches(compare.env_of(base),
-                                   compare.env_of(new))
-    assert len(diffs) == 1 and "jax" in diffs[0]
-    assert compare.env_mismatches(compare.env_of(base),
-                                  compare.env_of(base)) == []
-    assert "no environment stamp" in \
-        compare.env_mismatches(None, compare.env_of(new))[0]
-
-
-def test_measure_partition_caches_by_name():
-    sys.path.insert(0, ROOT)
-    try:
-        from benchmarks import common
-    finally:
-        sys.path.remove(ROOT)
-    saved_records = list(common.RECORDS)
-    saved_reports = dict(common.REPORTS)
-    try:
-        common.RECORDS.clear()
-        common.REPORTS.clear()
-        g = small_gamma(24)
-        rep1, rec1 = common.measure_partition("t.case", "jag-pq-opt", g, 4,
-                                              repeats=1)
-        n_after_first = len(common.RECORDS)
-        rep2, rec2 = common.measure_partition("t.case", "jag-pq-opt", g, 4,
-                                              repeats=1)
-        assert rep2 is rep1 and rec2 is rec1
-        assert len(common.RECORDS) == n_after_first  # no re-emission
-        assert rec1["bottleneck"] == rep1.bottleneck
-        assert rec1["spans"] and rec1["counters"]
-    finally:
-        common.RECORDS[:] = saved_records
-        common.REPORTS.clear()
-        common.REPORTS.update(saved_reports)
+# demo script
 
 
 def test_trace_demo_writes_valid_chrome_trace(tmp_path):

@@ -23,6 +23,7 @@ import pytest
 
 import repro
 from repro.dist import ctx
+from repro.kernels.sat.ref import gamma_ref
 from repro.rebalance import batch_device, planner, stream
 
 P, M = 3, 10
@@ -222,12 +223,12 @@ def dataclasses_tuple(rec):
 # batched Pallas SAT under the planner stages
 
 
-def test_sat_stage_pallas_batch_matches_oracle():
+def test_sat_stage_pallas_batch_matches_oracle(pallas_kernels):
     """The Pallas path takes the (T, n1, n2) batch through its leading
     grid axis (no per-frame fallback) and, on integer-valued f32 frames,
     matches the jnp oracle exactly."""
     frames = jnp.asarray(stream.static(3, 20, 28), jnp.float32)
-    got = planner.sat_stage(frames, use_pallas=True, interpret=True)
-    want = planner.sat_stage(frames, use_pallas=False)
+    got = planner.sat_stage(frames)
+    want = gamma_ref(frames)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert got.shape == (3, 21, 29)

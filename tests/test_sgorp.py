@@ -133,15 +133,19 @@ def test_plan_stream_rank4_dispatch():
         planner.plan_stream_3d(frames, m=8, grid=(2, 2, 1))
 
 
-def test_plan3d_pallas_interpret_matches_oracle():
+def test_plan3d_pallas_interpret_matches_oracle(pallas_kernels):
     """The rank-3 Pallas SAT inside the planning chain (interpret mode)
-    must not change the cuts vs the jnp oracle (f32 sums of int-valued
-    loads are exact at this scale)."""
+    must not change the cuts vs the same plan on the jnp oracle's Gamma3
+    (f32 sums of int-valued loads are exact at this scale)."""
+    from repro.kernels.sat.ref import gamma3_ref
     from repro.rebalance import planner
     frames = _frames(T=2)
-    ref = planner.plan_stream_3d(frames, m=8, use_pallas=False)
-    got = planner.plan_stream_3d(frames, m=8, use_pallas=True,
-                                 interpret=True)
+    got = planner.plan_stream_3d(frames, m=8)
+    grid = sgorp.default_grid(8, frames.shape[1:])
+    cuts, *rest = jax.vmap(lambda g: sgorp.sgorp_plan_impl(g, grid=grid))(
+        gamma3_ref(jnp.asarray(frames, jnp.float32)))
+    ref = (*cuts, *rest)
+    assert len(got) == len(ref) == 6
     for a, b in zip(got, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

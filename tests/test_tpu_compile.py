@@ -57,6 +57,12 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture
+def compiled_kernels(pallas_kernels, monkeypatch):
+    """The planner takes its Pallas kernels, compiled for the chip."""
+    monkeypatch.setenv("JAX_PALLAS_INTERPRET", "0")
+
+
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -91,18 +97,17 @@ def test_rectload_compiles(one_chip, dtype):
     _compile(lambda a, b, c: jagged_loads_pallas(a, b, c), g, rc, cc)
 
 
-def test_exact_plan_frames_compiles_with_kernels(one_chip):
+def test_exact_plan_frames_compiles_with_kernels(one_chip, compiled_kernels):
     """SAT -> exact JAG-PQ-OPT with the probe kernel inside its
     while_loops, the whole stream in one program, fits one chip."""
     x = jax.ShapeDtypeStruct((T, N, N), jnp.int32, sharding=one_chip)
     compiled = _compile(functools.partial(
-        planner.plan_frames, P=P, m=P * Q, exact=True, use_pallas=True,
-        interpret=False), x)
+        planner.plan_frames, P=P, m=P * Q, exact=True), x)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
 
 
-def test_sharded_exact_plan_compiles_on_four_chips(topo):
+def test_sharded_exact_plan_compiles_on_four_chips(topo, compiled_kernels):
     """The frame-sharded chain over all four chips of the host."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -112,5 +117,5 @@ def test_sharded_exact_plan_compiles_on_four_chips(topo):
                              sharding=NamedSharding(mesh,
                                                     PartitionSpec("data")))
     fn = planner._sharded_plan_fn(mesh, P, P * Q, 8, 8, jnp.dtype(jnp.int32),
-                                  True, False, True)
+                                  True)
     assert "tpu_custom_call" in fn.lower(x).compile().as_text()
