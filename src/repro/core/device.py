@@ -4,8 +4,10 @@ The paper's NicolPlus machinery is pointer-chasing parametric search — fine
 on a host CPU, hostile to a TPU's vector units. We restructure it:
 
 - ``probe_device``: the Han-et-al greedy probe as a ``lax.scan`` of
-  ``searchsorted`` steps, *vectorized over a batch of candidate bottleneck
-  values* (the VPU sweeps many L values at the price of one).
+  one-pass greedy steps (``_advance``: one compare-and-reduce over the
+  row, carrying the prefix value, no search), *vectorized over a batch
+  of candidate bottleneck values* (the VPU sweeps many L values at the
+  price of one).
 - ``wide_bisect_device``: the device twin of ``search.bisect_bottleneck`` —
   each round probes K ascending candidates spanning [lo, hi] simultaneously,
   shrinking the interval by (K+1)x per round instead of 2x; the default 8
@@ -60,32 +62,54 @@ from repro.backend import use_pallas_default
 # probes
 
 
-def _advance(p: jnp.ndarray, pos: jnp.ndarray, L: jnp.ndarray) -> jnp.ndarray:
-    """One greedy step: furthest index e with p[e] <= p[pos] + L, > pos."""
-    target = jnp.take(p, pos) + L
-    nxt = jnp.searchsorted(p, target, side="right") - 1
-    nxt = jnp.minimum(nxt, p.shape[0] - 1)
-    return jnp.maximum(nxt, pos)  # stuck (single element > L) stays stuck
+def _advance(p: jnp.ndarray, pos: jnp.ndarray, v: jnp.ndarray,
+             L: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One greedy step: furthest index e with p[e] <= p[pos] + L, >= pos.
+
+    ``v`` is ``p[pos]``, carried by the caller's loop; returns ``(e,
+    p[e])``.  ``pos``, ``v`` and ``L`` may carry batch dimensions; ``p``
+    is one row.  One compare and one reduction over the row that keeps
+    the (index, value) pair with the larger index: no dependent lookups,
+    no gather.  On a non-decreasing ``p``, e is ``searchsorted(p, v + L,
+    "right") - 1`` clipped to ``[pos, n]``, bit for bit; a float32
+    stripe prefix need not be monotone, and e is then still the largest
+    such index.
+    """
+    le = p <= (v + L)[..., None]
+    axis = le.ndim - 1
+    idx = jnp.where(le, jax.lax.broadcasted_iota(jnp.int32, le.shape, axis),
+                    -1)
+    zero = jnp.zeros((), p.dtype)
+
+    def later(a, b):
+        take_b = b[0] > a[0]
+        return (jax.lax.select(take_b, b[0], a[0]),
+                jax.lax.select(take_b, b[1], a[1]))
+
+    e, w = jax.lax.reduce((idx, jnp.where(le, p, zero)), (jnp.int32(-1), zero),
+                          later, (axis,))
+    stuck = e < pos  # a single element > L: the step stays
+    return jnp.where(stuck, pos, e), jnp.where(stuck, v, w)
 
 
 def probe_device(p: jnp.ndarray, m: int, Ls: jnp.ndarray) -> jnp.ndarray:
     """Feasibility of each candidate bottleneck in ``Ls`` ((B,) bool)."""
-    pos0 = jnp.zeros(Ls.shape, dtype=jnp.int32)
+    init = (jnp.zeros(Ls.shape, jnp.int32), jnp.broadcast_to(p[0], Ls.shape))
 
-    def step(pos, _):
-        return _advance(p, pos, Ls), None
+    def step(carry, _):
+        return _advance(p, *carry, Ls), None
 
-    pos, _ = jax.lax.scan(step, pos0, None, length=m)
+    (pos, _), _ = jax.lax.scan(step, init, None, length=m)
     return pos == p.shape[0] - 1
 
 
 def probe_cuts_device(p: jnp.ndarray, m: int, L: jnp.ndarray) -> jnp.ndarray:
     """Cut array (m+1,) realizing bottleneck L (garbage if infeasible)."""
-    def step(pos, _):
-        nxt = _advance(p, pos[None], L)[0]
-        return nxt, nxt
+    def step(carry, _):
+        carry = _advance(p, *carry, L)
+        return carry, carry[0]
 
-    _, cuts = jax.lax.scan(step, jnp.int32(0), None, length=m)
+    _, cuts = jax.lax.scan(step, (jnp.int32(0), p[0]), None, length=m)
     return jnp.concatenate([jnp.zeros(1, jnp.int32), cuts])
 
 
@@ -139,11 +163,19 @@ def optimal_1d_device(p: jnp.ndarray, m: int, *, k: int = 8,
 # masked per-stripe probe (variable processor counts, static shapes)
 
 
-def _masked_step(p, count, L, i, pos):
-    """Greedy step ``i`` of a ``count``-interval probe: advance while
-    ``i < count``; the last live interval (``i == count - 1``) runs to n."""
-    nxt = jnp.where(i < count, _advance(p, pos[None], L)[0], pos)
-    return jnp.where(i == count - 1, p.shape[0] - 1, nxt)
+def _masked_step(p, count, L, i, pos, v, pn):
+    """Greedy step ``i`` of a ``count``-interval probe from ``pos`` (``v``
+    is ``p[pos]``): advance while ``i < count``; the last live interval
+    (``i == count - 1``) runs to n.  Returns the new ``(pos, p[pos])``.
+
+    ``pn`` is ``p[n]``, read once before the loop: read inside it, the
+    v5e compile copies the stripe rows to another layout at every step.
+    """
+    nxt, w = _advance(p, pos, v, L)
+    live = i < count
+    nxt, w = jnp.where(live, nxt, pos), jnp.where(live, w, v)
+    last = i == count - 1
+    return jnp.where(last, p.shape[0] - 1, nxt), jnp.where(last, pn, w)
 
 
 def _probe_cuts_masked(p: jnp.ndarray, m_max: int, count: jnp.ndarray,
@@ -155,15 +187,16 @@ def _probe_cuts_masked(p: jnp.ndarray, m_max: int, count: jnp.ndarray,
     without being visited.
     """
     n = p.shape[0] - 1
+    pn = p[n]
 
     def step(i, carry):
-        pos, cuts = carry
-        nxt = _masked_step(p, count, L, i, pos)
-        return nxt, cuts.at[i + 1].set(nxt)
+        pos, v, cuts = carry
+        nxt, w = _masked_step(p, count, L, i, pos, v, pn)
+        return nxt, w, cuts.at[i + 1].set(nxt)
 
     cuts0 = jnp.full(m_max + 1, n, jnp.int32).at[0].set(0)
-    _, cuts = jax.lax.fori_loop(jnp.int32(0), steps, step,
-                                (jnp.int32(0), cuts0))
+    _, _, cuts = jax.lax.fori_loop(jnp.int32(0), steps, step,
+                                   (jnp.int32(0), p[0], cuts0))
     return cuts
 
 
@@ -172,20 +205,21 @@ def _probe_bottleneck_masked(p: jnp.ndarray, m_max: int, count: jnp.ndarray,
                              steps: jnp.ndarray) -> jnp.ndarray:
     """``_stripe_bottleneck(p, _probe_cuts_masked(...))`` without the cuts.
 
-    The same float subtractions, folded into a running max; the m_max -
-    count intervals never visited are n..n, load 0 (a float32 stripe
-    prefix need not be monotone, so no interval is assumed >= 0).
+    The same float subtractions ``p[nxt] - p[pos]``, of the carried
+    prefix values, folded into a running max; the m_max - count
+    intervals never visited are n..n, load 0 (a float32 stripe prefix
+    need not be monotone, so no interval is assumed >= 0).
     """
     worst0 = jnp.where(count < m_max, 0, -jnp.inf).astype(p.dtype)
+    pn = p[-1]
 
     def step(i, carry):
-        pos, worst = carry
-        nxt = _masked_step(p, count, L, i, pos)
-        load = jnp.take(p, nxt) - jnp.take(p, pos)
-        return nxt, jnp.maximum(worst, load)
+        pos, v, worst = carry
+        nxt, w = _masked_step(p, count, L, i, pos, v, pn)
+        return nxt, w, jnp.maximum(worst, w - v)
 
-    _, worst = jax.lax.fori_loop(jnp.int32(0), steps, step,
-                                 (jnp.int32(0), worst0))
+    _, _, worst = jax.lax.fori_loop(jnp.int32(0), steps, step,
+                                    (jnp.int32(0), p[0], worst0))
     return worst
 
 
@@ -408,13 +442,16 @@ def _greedy_cuts_exact(p: jnp.ndarray, m: int, L: jnp.ndarray) -> jnp.ndarray:
     to ``search.realize`` output for integer loads.
     """
     n = p.shape[0] - 1
+    pn = p[n]  # read before the loop, as in ``_masked_step``
 
-    def step(pos, _):
-        rem_fits = p[n] - jnp.take(p, pos) <= L
-        out = jnp.where(rem_fits, pos, _advance(p, pos, L))
-        return out, out
+    def step(carry, _):
+        pos, v = carry
+        rem_fits = pn - v <= L
+        nxt, w = _advance(p, pos, v, L)
+        out = jnp.where(rem_fits, pos, nxt), jnp.where(rem_fits, v, w)
+        return out, out[0]
 
-    _, cuts = jax.lax.scan(step, jnp.int32(0), None, length=m)
+    _, cuts = jax.lax.scan(step, (jnp.int32(0), p[0]), None, length=m)
     cuts = jnp.concatenate([jnp.zeros(1, jnp.int32), cuts])
     return cuts.at[m].set(n)
 

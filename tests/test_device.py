@@ -52,8 +52,131 @@ def test_device_jag_m_heur_matches_host(rng):
 
 
 # ---------------------------------------------------------------------------
-# JAG-M-HEUR stripe probes: a loop of max(counts) steps against the former
-# static m - P + 1 scan, kept here as the reference
+# the greedy step: one compare-and-reduce pass against the former
+# ``searchsorted`` step, kept here as the reference
+
+
+def _searchsorted_advance(p, pos, L):
+    """The former ``_advance``: furthest index e with p[e] <= p[pos] + L,
+    >= pos, by ``searchsorted``."""
+    target = jnp.take(p, pos) + L
+    nxt = jnp.searchsorted(p, target, side="right") - 1
+    nxt = jnp.minimum(nxt, p.shape[0] - 1)
+    return jnp.maximum(nxt, pos)  # stuck (single element > L) stays stuck
+
+
+def _former_advance(p, pos, v, L):
+    """``_searchsorted_advance`` in the signature of ``device._advance``."""
+    nxt = _searchsorted_advance(p, pos, L)
+    return nxt, jnp.take(p, nxt)
+
+
+def _step_rows():
+    rng = np.random.default_rng(19)
+    loads = rng.integers(0, 6, 64)
+    loads[[0, 1, 2, 17, 18, 40, 63]] = 0           # zero-load runs
+    flat = np.full(33, 4)                           # every prefix repeats
+    flat[10:20] = 0
+    spiky = rng.integers(0, 3, 48)
+    spiky[[5, 30]] = 200                            # steps that stick
+    return {name: np.concatenate([[0], np.cumsum(a)])
+            for name, a in (("zero-runs", loads), ("flat", flat),
+                            ("spiky", spiky))}
+
+
+_STEP_ROWS = _step_rows()
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+@pytest.mark.parametrize("row", list(_STEP_ROWS))
+def test_advance_matches_searchsorted(row, dtype):
+    """On a non-decreasing row the one-pass step equals the former
+    ``searchsorted`` step bit for bit, from every position at every L
+    from 0 past the row's total, batched as ``probe_device`` calls it."""
+    p = jnp.asarray(_STEP_ROWS[row], dtype)
+    n = p.shape[0] - 1
+    pos = jnp.arange(n + 1, dtype=jnp.int32)
+    for L in range(0, int(p[n]) + 3):
+        Ls = jnp.full(pos.shape, L, dtype)
+        nxt, w = jax.jit(device._advance)(p, pos, p[pos], Ls)
+        want, want_w = _former_advance(p, pos, p[pos], Ls)
+        np.testing.assert_array_equal(np.asarray(nxt), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(want_w))
+    # a scalar position, as the loops over stripes and candidates call it
+    nxt, w = device._advance(p, jnp.int32(3), p[3], dtype(7))
+    assert int(nxt) == int(_searchsorted_advance(p, jnp.int32(3), dtype(7)))
+    assert w == p[nxt]
+
+
+def test_advance_non_monotone_row():
+    """A float32 stripe prefix need not be monotone: the step returns the
+    largest e >= pos with q[e] <= q[pos] + L (pos when there is none),
+    and q[e]."""
+    q = np.array([0.0, 3.0, 2.5, 6.0, 5.75, 5.75, 9.0, 8.5, 12.0, 7.0],
+                 np.float32)
+    n = len(q) - 1
+    qj = jnp.asarray(q)
+    pos = jnp.arange(n + 1, dtype=jnp.int32)
+    for L in np.arange(0.0, 13.0, 0.25, dtype=np.float32):
+        nxt, w = jax.jit(device._advance)(qj, pos, qj[pos],
+                                          jnp.full(pos.shape, L))
+        for s in range(n + 1):
+            fits = [e for e in range(s, n + 1) if q[e] <= q[s] + L]
+            want = max(fits, default=s)
+            assert int(nxt[s]) == want, (s, L)
+            assert float(w[s]) == q[want]
+    # the searchsorted step it replaces reads another index on this row
+    nxt, _ = device._advance(qj, jnp.int32(0), qj[0], jnp.float32(7.5))
+    assert int(nxt) == 9
+    assert int(_searchsorted_advance(qj, jnp.int32(0), 7.5)) != 9
+
+
+def _loop_depth(jaxpr) -> int:
+    """Deepest nest of sequential loops (``scan``/``while``) in a jaxpr."""
+    depth = 0
+    for eqn in jaxpr.eqns:
+        inner = 0
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            inner = max(inner, _loop_depth(sub))
+        if eqn.primitive.name in ("scan", "while"):
+            inner += 1
+        depth = max(depth, inner)
+    return depth
+
+
+@pytest.mark.parametrize("probe", ["probe_device", "probe_cuts_device",
+                                   "bottleneck_masked", "cuts_masked",
+                                   "greedy_cuts_exact"])
+def test_heur_probe_loop_depth(probe, monkeypatch):
+    """The greedy probes are one loop over the steps; nothing loops inside
+    a step (the former ``searchsorted`` step nests a loop of its own)."""
+    p = jnp.asarray(_STEP_ROWS["zero-runs"], jnp.float32)
+    m = 6
+
+    def depth():
+        # a new function each time: make_jaxpr caches traces by function
+        fn = {
+            "probe_device": lambda L: device.probe_device(
+                p, m, L + jnp.ones(8)),
+            "probe_cuts_device": lambda L: device.probe_cuts_device(p, m, L),
+            "bottleneck_masked": lambda L: device._probe_bottleneck_masked(
+                p, m, jnp.int32(4), L, jnp.int32(5)),
+            "cuts_masked": lambda L: device._probe_cuts_masked(
+                p, m, jnp.int32(4), L, jnp.int32(5)),
+            "greedy_cuts_exact": lambda L: device._greedy_cuts_exact(
+                p.astype(jnp.int32), m, L.astype(jnp.int32)),
+        }[probe]
+        return _loop_depth(jax.make_jaxpr(fn)(jnp.float32(20)).jaxpr)
+
+    assert depth() == 1
+    monkeypatch.setattr(device, "_advance", _former_advance)
+    assert depth() == 2
+
+
+# ---------------------------------------------------------------------------
+# JAG-M-HEUR stripe probes: a loop of max(counts) one-pass steps against
+# the former static m - P + 1 scan of ``searchsorted`` steps, kept here as
+# the reference
 
 
 def _static_probe_cuts(p, m_max, count, L, steps=None):
@@ -61,7 +184,7 @@ def _static_probe_cuts(p, m_max, count, L, steps=None):
     n = p.shape[0] - 1
 
     def step(pos, i):
-        nxt = jnp.where(i < count, device._advance(p, pos[None], L)[0], pos)
+        nxt = jnp.where(i < count, _searchsorted_advance(p, pos, L), pos)
         nxt = jnp.where(i == count - 1, n, nxt)
         return nxt, nxt
 
@@ -97,18 +220,32 @@ def _heur_cases():
 _HEUR_CASES = _heur_cases()
 
 
-def _heur_reference(monkeypatch, fn, *args):
-    """``fn(*args)`` traced anew with the static scan in place of the loop."""
-    with monkeypatch.context() as mp:
-        mp.setattr(device, "_probe_cuts_masked", _static_probe_cuts)
-        mp.setattr(device, "_probe_bottleneck_masked",
-                   _static_probe_bottleneck)
-        return jax.jit(lambda *a: fn(*a))(*args)
+def _former_step_reference(monkeypatch, fn, *args, static=True):
+    """``fn(*args)`` traced anew with the former ``searchsorted`` step (and,
+    where ``static``, the static scan in place of the bounded loop).  The
+    jit caches are cleared around it, so no trace of ``optimal_1d_device``
+    crosses the patch in either direction."""
+    jax.clear_caches()
+    try:
+        with monkeypatch.context() as mp:
+            mp.setattr(device, "_advance", _former_advance)
+            if static:
+                mp.setattr(device, "_probe_cuts_masked", _static_probe_cuts)
+                mp.setattr(device, "_probe_bottleneck_masked",
+                           _static_probe_bottleneck)
+            return jax.jit(lambda *a: fn(*a))(*args)
+    finally:
+        jax.clear_caches()
 
 
-@pytest.mark.parametrize("case", [*_HEUR_CASES, "plan_frames-T3"])
-def test_jag_m_heur_bounded_loop_bit_identical(case, monkeypatch):
-    if case == "plan_frames-T3":
+@pytest.mark.parametrize("case", [*_HEUR_CASES, "plan_frames-T3",
+                                  "plan_frames-T3-pallas"])
+def test_jag_m_heur_bounded_loop_bit_identical(case, monkeypatch, request):
+    """Row cuts, counts, column cuts and Lmax of JAG-M-HEUR equal those of
+    the former static scan of ``searchsorted`` steps on every case."""
+    if case.startswith("plan_frames-T3"):
+        if case.endswith("pallas"):
+            request.getfixturevalue("pallas_kernels")
         frames = np.stack([_HEUR_CASES[c][0][:24, :40] for c in
                            ("one-heavy-row", "uniform", "two-heavy-rows")])
         P, m = 4, 16
@@ -125,17 +262,42 @@ def test_jag_m_heur_bounded_loop_bit_identical(case, monkeypatch):
 
         arg = jnp.asarray(prefix.prefix_sum_2d(A), jnp.float32)
     got = jax.jit(fn)(arg)
-    want = _heur_reference(monkeypatch, fn, arg)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    want = _former_step_reference(monkeypatch, fn, arg)
+    for name, g, w in zip(("row_cuts", "counts", "col_cuts", "lmax"),
+                          got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
     counts = np.asarray(got[1]).reshape(-1, P)
     m_max = m - P + 1
     if case == "one-heavy-row":
         assert counts.min() == 1 and counts.max() == m_max
     if case == "two-heavy-rows":
         assert counts.min() == 1 and 1 < counts.max() < m_max
-    if case == "plan_frames-T3":
+    if case.startswith("plan_frames-T3"):
         assert len(set(counts.max(axis=1).tolist())) == 3
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("case", list(_HEUR_CASES))
+def test_optimal_1d_onepass_bit_identical(case, axis, monkeypatch):
+    """``optimal_1d_device`` (JAG-M-HEUR's rows, SGORP's warm start) and
+    the CPU branch of ``nicol_optimal_device`` give the cuts and bottleneck
+    of the former ``searchsorted`` step, on the row and column prefixes
+    of every case."""
+    A, P, m = _HEUR_CASES[case]
+    p = np.concatenate([[0], np.cumsum(A.sum(axis=1 - axis))])
+
+    def fn(pf, pi):
+        return (*device.optimal_1d_device(pf, m),
+                *device.nicol_optimal_device(pi, m))
+
+    args = jnp.asarray(p, jnp.float32), jnp.asarray(p, jnp.int32)
+    got = jax.jit(fn)(*args)
+    want = _former_step_reference(monkeypatch, fn, *args, static=False)
+    for name, g, w in zip(("cuts", "L", "exact_cuts", "exact_L"),
+                          got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
 
 
 # ---------------------------------------------------------------------------
@@ -241,19 +403,6 @@ def test_exact_row_onepass_bit_identical(case, monkeypatch):
         assert A[:, 11].max() > A.sum() / (P * Q)
     if case == "L-at-prefix":
         assert int(got[3]) == 32
-
-
-def _loop_depth(jaxpr) -> int:
-    """Deepest nest of sequential loops (``scan``/``while``) in a jaxpr."""
-    depth = 0
-    for eqn in jaxpr.eqns:
-        inner = 0
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            inner = max(inner, _loop_depth(sub))
-        if eqn.primitive.name in ("scan", "while"):
-            inner += 1
-        depth = max(depth, inner)
-    return depth
 
 
 @pytest.mark.parametrize("form", ["feasible", "realize", "speeds"])
